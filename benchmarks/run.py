@@ -122,6 +122,8 @@ def main(argv: Optional[List[str]] = None) -> None:
         # invocation typo silently uploading an empty BENCH_results.json
         ap.error(f"--only selected no benchmark modules "
                  f"(have {list(MODULES)})")
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     rows: List[Tuple[str, str, float, str]] = []
     print("name,us_per_call,derived")

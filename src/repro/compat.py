@@ -1,98 +1,51 @@
-"""JAX version-compat shims, centralised.
+"""The JAX sharding calls the mesh code shares, written for JAX 0.9.
 
-The repo targets a range of JAX versions; the pinned container ships
-0.4.x, where several APIs the newer code paths use do not exist yet:
-
-  * ``jax.sharding.AxisType`` / ``jax.make_mesh(..., axis_types=...)``
-    (explicit-sharding axis typing landed in 0.5/0.6);
-  * ``jax.shard_map`` as a top-level API with ``check_vma=`` (0.4.x has
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep=``);
-  * ``jax.lax.pvary`` and ``jax.typeof(...).vma`` (varying-manual-axes
-    typing).
-
-Everything that needs one of these goes through this module so the
-version split lives in exactly one place.  All helpers degrade to the
-closest older-API equivalent, never to a behaviour change.
+The code targets the installed JAX (0.9.0): top-level ``jax.shard_map``
+with varying-manual-axes typing (``check_vma``), ``jax.sharding.AxisType``
+and ``lax.axis_size``/``lax.pcast``.  These wrappers give the mesh code
+one spelling of each; if a later JAX renames one of them, this module is
+the one place to change (``tests/test_skeleton.py`` asserts that no
+version probe lives anywhere else).
 """
 from __future__ import annotations
 
 from typing import Any, Optional, Sequence
 
 import jax
+from jax import lax
 
-__all__ = ["HAS_AXIS_TYPE", "HAS_TOP_LEVEL_SHARD_MAP", "HAS_PVARY",
-           "HAS_AXIS_SIZE", "WHILE_NEEDS_UNCHECKED_REP", "make_mesh",
-           "shard_map", "pvary", "needs_pvary", "axis_size", "vma_align"]
-
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-HAS_TOP_LEVEL_SHARD_MAP = hasattr(jax, "shard_map")
-HAS_PVARY = hasattr(jax.lax, "pvary")
-HAS_AXIS_SIZE = hasattr(jax.lax, "axis_size")
-
-# 0.4.x's experimental shard_map replication checker has no rule for
-# ``lax.while_loop`` ("No replication rule for while"); the vma-typed
-# checker that ships with top-level ``jax.shard_map`` does.  Callers putting
-# a while_loop inside shard_map (the skeleton mesh backend's feedback farm)
-# must disable the checker on old JAX — behaviour is unchanged, only the
-# static replication audit is skipped.
-WHILE_NEEDS_UNCHECKED_REP = not HAS_TOP_LEVEL_SHARD_MAP
+__all__ = ["make_mesh", "shard_map", "pvary", "needs_pvary", "axis_size",
+           "vma_align"]
 
 
 def axis_size(axis_name: str) -> int:
-    """Static size of a manual mesh axis, from inside ``shard_map``.
-
-    ``lax.axis_size`` is recent; on older JAX the classic idiom
-    ``psum(1, axis)`` constant-folds to the same static int."""
-    if HAS_AXIS_SIZE:
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """Static size of a manual mesh axis, from inside ``shard_map``."""
+    return lax.axis_size(axis_name)
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
-              *, auto: bool = True) -> "jax.sharding.Mesh":
-    """``jax.make_mesh`` with ``AxisType.Auto`` axes where supported.
-
-    On JAX without ``jax.sharding.AxisType`` every mesh axis is implicitly
-    auto, so simply omitting ``axis_types`` is the exact equivalent.
-    """
-    if HAS_AXIS_TYPE and auto:
-        types = (jax.sharding.AxisType.Auto,) * len(tuple(axis_names))
-        return jax.make_mesh(axis_shapes, axis_names, axis_types=types)
-    return jax.make_mesh(axis_shapes, axis_names)
+              ) -> "jax.sharding.Mesh":
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    types = (jax.sharding.AxisType.Auto,) * len(tuple(axis_names))
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=types)
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs,
               check_vma: Optional[bool] = None):
-    """Top-level ``jax.shard_map`` if present, else the 0.4.x experimental
-    one.  ``check_vma`` maps onto the older ``check_rep`` (both toggle the
-    replication/varying-axes checker)."""
-    if HAS_TOP_LEVEL_SHARD_MAP:
-        kwargs = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kwargs = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kwargs)
+    """``jax.shard_map``; ``check_vma=None`` keeps JAX's default (on)."""
+    kwargs = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def pvary(x: Any, axis_names: Sequence[str]) -> Any:
-    """``lax.pvary`` where it exists; identity on older JAX, where manual
-    values are not tracked as axis-varying and no cast is needed."""
-    if HAS_PVARY:
-        return jax.lax.pvary(x, tuple(axis_names))
-    return x
+    """Mark ``x`` as varying over ``axis_names`` (``lax.pcast``)."""
+    return lax.pcast(x, tuple(axis_names), to="varying")
 
 
 def needs_pvary(x: Any, axis_name: str) -> bool:
-    """True if ``x`` does not yet vary over ``axis_name`` (shard_map vma
-    typing).  Always False on JAX without vma typing."""
-    if not HAS_PVARY:
-        return False
-    try:
-        return axis_name not in jax.typeof(x).vma
-    except Exception:  # pragma: no cover - vma typing shape changed
-        return False
+    """True if ``x`` does not yet vary over ``axis_name``."""
+    return axis_name not in jax.typeof(x).vma
 
 
 def vma_align(x: Any, axis_names: Sequence[str]) -> Any:
@@ -101,11 +54,8 @@ def vma_align(x: Any, axis_names: Sequence[str]) -> Any:
 
     The skeleton mesh lowering mixes values of different provenance inside
     one ``shard_map`` body — stage-invariant microbatches, worker-varying
-    farm buffers, ``axis_index``-derived stage selectors — and newer JAX's
+    farm buffers, ``axis_index``-derived stage selectors — and the
     varying-manual-axes typing requires the operands of ``select_n`` /
-    ``where`` / ``ppermute`` to agree.  On JAX without vma typing (0.4.x)
-    manual values carry no axis-varying type and this is the identity."""
-    if not HAS_PVARY:
-        return x
+    ``where`` / ``ppermute`` to agree."""
     missing = tuple(a for a in axis_names if needs_pvary(x, a))
     return pvary(x, missing) if missing else x

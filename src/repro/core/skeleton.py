@@ -1398,10 +1398,6 @@ class MeshProgram:
 
         stages, W = self.stages, self.n_worker
         pipelined = self.n_stage > 1        # one stage per mesh row
-        check_vma = self.check_vma
-        if check_vma is None and compat.WHILE_NEEDS_UNCHECKED_REP \
-                and any(st.kind == "feedback" for st in stages):
-            check_vma = False               # see compat.WHILE_NEEDS_UNCHECKED_REP
 
         def apply_stage(st: _MeshStage, xf):
             # xf carries the payload plus the validity-flag column; stages
@@ -1454,7 +1450,7 @@ class MeshProgram:
 
         fn = jax.jit(compat.shard_map(
             body, mesh=self.mesh, in_specs=(P(WORKER_AXIS),),
-            out_specs=P(WORKER_AXIS), check_vma=check_vma))
+            out_specs=P(WORKER_AXIS), check_vma=self.check_vma))
         self._programs[key] = fn
         if self._lane is not None:
             self._lane.span("compile", t_compile, time.monotonic(),

@@ -52,7 +52,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         q = q_ref[0, 0].astype(jnp.float32)                   # (bq, D)
         k = k_ref[0, 0].astype(jnp.float32)                   # (bk, D)
         v = v_ref[0, 0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # (bq,bk)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale  # (bq,bk)
         qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         mask = kpos < seq_k
@@ -67,7 +68,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_scr[...][:, :1] * alpha + p.sum(axis=-1, keepdims=True)
         acc = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ()))).astype(jnp.float32)
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
         acc_scr[...] = acc
@@ -80,7 +82,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "bq", "bk", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
-                    bq: int = 128, bk: int = 512, interpret: bool = True):
+                    bq: int = 128, bk: int = 512, interpret: bool = False):
     """q (B,H,S,D); k/v (B,Hkv,T,D), H % Hkv == 0. Returns (B,H,S,D)."""
     B, H, S, D = q.shape
     Hkv, T = k.shape[1], k.shape[2]

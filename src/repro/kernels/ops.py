@@ -1,25 +1,32 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True everywhere in this repo because the container
-is CPU-only; on a real TPU runtime set ``REPRO_PALLAS_COMPILE=1`` (or pass
-``interpret=False``) to lower the kernels natively.
+``interpret=None`` (the default) resolves from the backend: the Pallas
+interpreter on the CPU backend, the compiled Mosaic kernel everywhere else.
+Module-level tables are numpy, so importing this module never touches a
+device.
 """
 from __future__ import annotations
 
-import os
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .flash_attention import flash_attention as _flash
 from .smith_waterman import sw_pallas as _sw
 from .ssd_scan import ssd_scan as _ssd
 
-__all__ = ["smith_waterman", "flash_attention_op", "ssd_scan_op",
-           "build_profile", "BLOSUM50", "AA_ALPHABET", "encode_seq"]
+__all__ = ["smith_waterman", "smith_waterman_padded", "pad_subject",
+           "flash_attention_op", "ssd_scan_op", "build_profile", "BLOSUM50",
+           "AA_ALPHABET", "encode_seq", "resolve_interpret"]
 
-_INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """Interpret only where Mosaic cannot run: the CPU backend."""
+    return jax.default_backend() == "cpu" if interpret is None else interpret
+
 
 # ---------------------------------------------------------------------------
 # Smith-Waterman front-end: alphabet, BLOSUM50, profiles
@@ -53,49 +60,66 @@ _B50 = """
 -1 -1 -1 -1 -2 -1 -1 -2 -1 -1 -1 -1 -1 -2 -2 -1  0 -3 -1 -1 -1 -1 -1 -5
 -5 -5 -5 -5 -5 -5 -5 -5 -5 -5 -5 -5 -5 -5 -5 -5 -5 -5 -5 -5 -5 -5 -5  1
 """
-BLOSUM50 = jnp.asarray(
+BLOSUM50 = np.asarray(
     [[int(v) for v in row.split()] for row in _B50.strip().splitlines()],
-    jnp.float32)
+    np.float32)
 
 
-def encode_seq(seq: str) -> jnp.ndarray:
+def encode_seq(seq: str) -> np.ndarray:
     lut = {c: i for i, c in enumerate(AA_ALPHABET)}
-    return jnp.asarray([lut.get(c, lut["X"]) for c in seq.upper()], jnp.int32)
+    return np.asarray([lut.get(c, lut["X"]) for c in seq.upper()], np.int32)
 
 
-def build_profile(query: jnp.ndarray, matrix: jnp.ndarray = BLOSUM50,
+def build_profile(query, matrix=BLOSUM50,
                   pad_to: int = 128) -> Tuple[jnp.ndarray, int]:
     """Farrar's query profile, TPU layout: (A, Qp) with Qp multiple of 128.
     Padded query positions score a large negative so they never align."""
     q_len = int(query.shape[0])
     qp = -(-q_len // pad_to) * pad_to
-    prof = matrix[:, query]                                 # (A, Q)
+    prof = jnp.asarray(matrix)[:, jnp.asarray(query)]       # (A, Q)
     prof = jnp.pad(prof, ((0, 0), (0, qp - q_len)), constant_values=-1e4)
     return prof, q_len
 
 
-def smith_waterman(query: jnp.ndarray, subject: jnp.ndarray, *,
-                   gap_open: float = 10.0, gap_extend: float = 2.0,
-                   matrix: jnp.ndarray = BLOSUM50, tile: int = 512,
+@functools.partial(jax.jit, static_argnames=("gap_open", "gap_extend",
+                                             "tile", "interpret"))
+def smith_waterman_padded(query, subject, matrix, *, gap_open, gap_extend,
+                          tile, interpret):
+    """The whole device program of one alignment: profile build + kernel.
+    ``subject`` must already be padded to a whole number of tiles
+    (:func:`pad_subject`)."""
+    prof, q_len = build_profile(query, matrix)
+    return _sw(prof, subject, gap_open=gap_open, gap_extend=gap_extend,
+               q_len=q_len, tile=tile, interpret=interpret)
+
+
+def smith_waterman(query, subject, *, gap_open: float = 10.0,
+                   gap_extend: float = 2.0, matrix=BLOSUM50, tile: int = 512,
                    interpret: Optional[bool] = None) -> jnp.ndarray:
     """Best local alignment score of two encoded sequences (the paper's
-    application, Sec. 4.2).  Handles padding internally."""
-    interpret = _INTERPRET if interpret is None else interpret
-    prof, q_len = build_profile(query, matrix)
-    dlen = int(subject.shape[0])
-    dp = -(-dlen // tile) * tile
-    subj = jnp.pad(subject, (0, dp - dlen), constant_values=matrix.shape[0])
-    return _sw(prof, subj, gap_open=gap_open, gap_extend=gap_extend,
-               q_len=q_len, tile=tile, interpret=interpret)
+    application, Sec. 4.2).  The subject is padded on the host to a whole
+    number of tiles, so one compiled program serves every subject length
+    in a tile bucket."""
+    subj = pad_subject(subject, tile, matrix.shape[0])
+    return smith_waterman_padded(query, subj, matrix, gap_open=float(gap_open),
+                                 gap_extend=float(gap_extend), tile=tile,
+                                 interpret=resolve_interpret(interpret))
+
+
+def pad_subject(subject, tile: int, fill: int) -> np.ndarray:
+    """Host-side pad of an encoded subject to a multiple of ``tile`` with
+    ``fill`` (a code >= the alphabet size, which the kernel skips)."""
+    subject = np.asarray(subject, np.int32)
+    dlen = subject.shape[0]
+    return np.pad(subject, (0, -(-dlen // tile) * tile - dlen),
+                  constant_values=fill)
 
 
 def flash_attention_op(q, k, v, *, causal=True, window=None,
                        interpret: Optional[bool] = None, **kw):
-    interpret = _INTERPRET if interpret is None else interpret
     return _flash(q, k, v, causal=causal, window=window,
-                  interpret=interpret, **kw)
+                  interpret=resolve_interpret(interpret), **kw)
 
 
 def ssd_scan_op(x, dt, A, B, C, *, chunk=256, interpret: Optional[bool] = None):
-    interpret = _INTERPRET if interpret is None else interpret
-    return _ssd(x, dt, A, B, C, chunk=chunk, interpret=interpret)
+    return _ssd(x, dt, A, B, C, chunk=chunk, interpret=resolve_interpret(interpret))

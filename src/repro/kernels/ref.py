@@ -10,11 +10,12 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 __all__ = ["sw_ref", "sw_numpy", "attention_ref", "ssd_ref"]
 
-NEG = jnp.float32(-1e9)
+NEG = np.float32(-1e9)
 
 
 # --------------------------------------------------------------------------
@@ -61,8 +62,7 @@ def sw_ref(profile: jnp.ndarray, subject: jnp.ndarray, gap_open: float,
 
 
 def sw_numpy(query: str, subject: str, score_fn, gap_open: float, gap_extend: float) -> float:
-    """Cell-by-cell numpy triple-check for tiny cases (used by tests only)."""
-    import numpy as np
+    """Cell-by-cell numpy triple-check (tests and the chip smoke check)."""
     Q, D = len(query), len(subject)
     H = np.zeros((Q + 1, D + 1))
     E = np.full((Q + 1, D + 1), -1e9)

@@ -7,15 +7,16 @@ profile) and replace the SSE mechanics with TPU-native ones:
 
   * the **query axis is the 128-lane vector axis**; the whole query column
     state (H, E) lives in VMEM as (rows=Q/128 · sublanes, 128 lanes);
-  * the subject **streams** through the kernel in HBM→VMEM tiles (the grid's
+  * the subject **streams** through the kernel in HBM→SMEM tiles (the grid's
     sequential dimension — this kernel is itself a FastFlow pipeline: one
     SPSC hop per tile, state carried in VMEM scratch);
   * Farrar's lazy-F loop is replaced by a **closed-form prefix-max**: with
     gap_open ≥ gap_extend, F[i,j] = max_{k<i}(Ĥ[k,j] + k·ge) − go − (i−1)·ge
     where Ĥ is H computed without F — one associative scan on the VPU,
     exact, no data-dependent iteration (which TPUs hate);
-  * substitution scores come from a dynamic row slice of the profile tile
-    resident in VMEM (profile[c] — one sublane read per subject char).
+  * each subject tile arrives as a ``(1, tile)`` block in SMEM, so a
+    character is a scalar read; its substitution scores are one ``pl.ds``
+    row read of the profile resident in VMEM (one sublane per char).
 
 Limitations (documented): the within-column prefix-max runs over the padded
 query length Qp; queries longer than one VMEM block (Qp ≤ 8192 comfortably)
@@ -51,7 +52,10 @@ def _prefix_max_exclusive(x: jnp.ndarray) -> jnp.ndarray:
 
 def _sw_kernel(profile_ref, subject_ref, out_ref, h_ref, e_ref, best_ref,
                *, gap_open: float, gap_extend: float, tile: int, q_len: int):
-    """Grid: (num_subject_tiles,) — sequential; column state in VMEM scratch."""
+    """Grid: (num_subject_tiles,) — sequential; column state in VMEM scratch.
+
+    ``subject_ref`` is one ``(1, tile)`` row of the subject in SMEM: each
+    character is a scalar read that selects one profile row (``pl.ds``)."""
     t = pl.program_id(0)
 
     @pl.when(t == 0)
@@ -60,18 +64,16 @@ def _sw_kernel(profile_ref, subject_ref, out_ref, h_ref, e_ref, best_ref,
         e_ref[...] = jnp.full_like(e_ref, NEG)
         best_ref[...] = jnp.zeros_like(best_ref)
 
-    prof = profile_ref[...]                      # (A, Qp) VMEM-resident tile
-    chars = subject_ref[...]                     # (tile,) int32 (padded with A)
-    qp = prof.shape[1]
-    idx = lax.broadcasted_iota(jnp.float32, (1, qp), 1)
-    qmask = idx < q_len                          # padded query lanes
+    n_codes, qp = profile_ref.shape
+    lane = lax.broadcasted_iota(jnp.int32, (1, qp), 1)
+    qmask = lane < q_len                         # padded query lanes
+    idx = lane.astype(jnp.float32)
 
     def per_char(j, carry):
-        h, e, best = carry                       # (1, Qp) each
-        c = chars[j]
-        valid = c < prof.shape[0]
-        row = jnp.clip(c, 0, prof.shape[0] - 1)
-        s = jax.lax.dynamic_slice_in_dim(prof, row, 1, axis=0)     # (1, Qp)
+        h, e, best = carry                       # (1, Qp), (1, Qp), (1, 1)
+        c = subject_ref[0, j]
+        valid = c < n_codes
+        s = profile_ref[pl.ds(jnp.minimum(c, n_codes - 1), 1), :]   # (1, Qp)
         e_new = jnp.maximum(h - gap_open, e - gap_extend)
         h_shift = jnp.concatenate([jnp.zeros((1, 1), h.dtype), h[:, :-1]], axis=1)
         h_hat = jnp.maximum(jnp.maximum(h_shift + s, e_new), 0.0)
@@ -80,28 +82,26 @@ def _sw_kernel(profile_ref, subject_ref, out_ref, h_ref, e_ref, best_ref,
         p = _prefix_max_exclusive(h_hat + idx * gap_extend)
         f = p - gap_open - (idx - 1.0) * gap_extend
         h_new = jnp.where(qmask, jnp.maximum(h_hat, f), 0.0)
-        best = jnp.maximum(best, jnp.max(h_new))
-        h = jnp.where(valid, h_new, h)
-        e = jnp.where(valid, e_new, e)
-        best = jnp.where(valid, best, carry[2])
-        return h, e, best
+        best_new = jnp.maximum(best, jnp.max(h_new, axis=1, keepdims=True))
+        return (jnp.where(valid, h_new, h), jnp.where(valid, e_new, e),
+                jnp.where(valid, best_new, best))
 
     h, e, best = lax.fori_loop(
-        0, tile, per_char, (h_ref[...], e_ref[...], best_ref[0, 0]))
+        0, tile, per_char, (h_ref[...], e_ref[...], best_ref[...]))
     h_ref[...] = h
     e_ref[...] = e
-    best_ref[...] = jnp.full_like(best_ref, best)
+    best_ref[...] = best
 
     @pl.when(t == pl.num_programs(0) - 1)
     def _emit():
-        out_ref[...] = jnp.full_like(out_ref, best_ref[0, 0])
+        out_ref[...] = best_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("gap_open", "gap_extend", "tile",
                                              "interpret", "q_len"))
 def sw_pallas(profile: jnp.ndarray, subject: jnp.ndarray, *, gap_open: float,
               gap_extend: float, q_len: int, tile: int = DEFAULT_TILE,
-              interpret: bool = True) -> jnp.ndarray:
+              interpret: bool = False) -> jnp.ndarray:
     """Best local-alignment score for one (query-profile, subject) pair.
 
     profile: (A, Qp) f32, Qp a multiple of 128; subject: (Dp,) int32 padded
@@ -120,7 +120,8 @@ def sw_pallas(profile: jnp.ndarray, subject: jnp.ndarray, *, gap_open: float,
         grid=grid,
         in_specs=[
             pl.BlockSpec((A, Qp), lambda t: (0, 0)),          # profile: resident
-            pl.BlockSpec((tile,), lambda t: (t,)),            # subject: streamed
+            pl.BlockSpec((None, 1, tile), lambda t: (t, 0, 0),  # subject: streamed
+                         memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((1, 1), lambda t: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
@@ -130,5 +131,5 @@ def sw_pallas(profile: jnp.ndarray, subject: jnp.ndarray, *, gap_open: float,
             pltpu.VMEM((1, 1), jnp.float32),    # running best
         ],
         interpret=interpret,
-    )(profile, subject)
+    )(profile, subject.reshape(Dp // tile, 1, tile))
     return out[0, 0]

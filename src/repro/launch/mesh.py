@@ -4,9 +4,9 @@ Defined as FUNCTIONS so importing this module never touches jax device
 state (device count is locked at first jax init — the dry-run sets
 XLA_FLAGS before importing anything else).
 
-Mesh construction goes through :func:`repro.compat.make_mesh` so the same
-helper serves every pinned JAX version (``axis_types=`` only exists on
-newer JAX); the tests build their meshes with the same helper.
+Mesh construction goes through :func:`repro.compat.make_mesh` (every
+axis ``AxisType.Auto``); the tests build their meshes with the same
+helper.
 """
 from __future__ import annotations
 

@@ -51,6 +51,7 @@ from ..core.skeleton import Farm, Source, compose, lower
 from ..core.spsc import SPSCQueue
 from ..models import decode_step as model_decode, init_cache, init_params
 from ..models.config import ModelConfig
+from ..runtime.compile_cache import enable_compile_cache
 
 __all__ = ["Request", "ServeEngine"]
 
@@ -87,8 +88,10 @@ class ServeEngine:
         self.cfg = cfg
         self.max_batch = max_batch
         self.max_len = max_len
-        self.params = params if params is not None else init_params(
-            cfg, jax.random.PRNGKey(seed))
+        # one jitted program: eager init would hold each stacked leaf's
+        # float32 draw on the device next to its cast copy
+        self.params = params if params is not None else jax.jit(
+            init_params, static_argnums=0)(cfg, jax.random.PRNGKey(seed))
         self.cache = init_cache(cfg, max_batch, max_len)
         # SPMC pool: slots are the pages (admitter allocs, collector frees)
         self.pool = PagePool(max_batch, nfreers=1)
@@ -307,8 +310,14 @@ def main():
     ap.add_argument("--arch", required=True, choices=sorted(ARCHS))
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=12)
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the reduced same-family config (CPU-sized) "
+                         "instead of the published one")
     args = ap.parse_args()
-    cfg = ARCHS[args.arch].smoke()
+    enable_compile_cache()
+    cfg = ARCHS[args.arch]
+    if args.smoke:
+        cfg = cfg.smoke()
     eng = ServeEngine(cfg, max_batch=4, max_len=256)
     rng = np.random.default_rng(0)
     t0 = time.time()

@@ -26,6 +26,7 @@ from ..models import init_params
 from ..optim import adamw_init
 from ..parallel.context import mesh_context
 from ..runtime.checkpoint import AsyncCheckpointer, latest_step, restore
+from ..runtime.compile_cache import enable_compile_cache
 from .steps import make_train_step
 
 
@@ -103,6 +104,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = ARCHS[args.arch]
     if args.smoke:
         cfg = cfg.smoke()

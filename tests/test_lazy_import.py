@@ -127,3 +127,14 @@ def test_monitor_stays_jax_free():
         "rep = analyze(mon.timeline)\n"
         "assert rep.render(), 'empty report render'\n"
         "assert 'jax' not in sys.modules, 'monitor imported jax'")
+
+
+def test_kernels_import_opens_no_device():
+    """Importing the kernels (and their references) builds numpy tables
+    only: no JAX backend starts, so a process that imports them does not
+    take the accelerator."""
+    _run_isolated(
+        "from jax._src import xla_bridge\n"
+        "import repro.kernels.ops, repro.kernels.ref\n"
+        "assert not xla_bridge.backends_are_initialized(), "
+        "'importing repro.kernels started a JAX backend'")

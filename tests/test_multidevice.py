@@ -3,8 +3,8 @@
 Device count is locked at first jax init, so these run in SUBPROCESSES with
 XLA_FLAGS=--xla_force_host_platform_device_count=8 while the main pytest
 session keeps the real single CPU device.  All mesh/shard_map construction
-goes through ``repro.compat`` so the same code runs on pinned 0.4.x JAX
-(no ``AxisType``, no top-level ``jax.shard_map``) and on newer releases.
+goes through ``repro.compat``, the one module that spells the JAX 0.9
+sharding API (``AxisType.Auto`` meshes, top-level ``jax.shard_map``).
 """
 import os
 import subprocess
