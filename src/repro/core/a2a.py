@@ -44,6 +44,7 @@ import zlib
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from . import graph as _graph
+from . import obs as _obs
 from . import procgraph as _procgraph
 from .skeleton import (GO_ON, AllToAll, EmitMany, FnNode, KeyBatch,
                        LoweringError, Pipeline, Skeleton, Stage, WORKER_AXIS,
@@ -559,8 +560,8 @@ class A2AMeshProgram:
         self._programs: Dict[Tuple[int, str], Callable] = {}
         self.tracer = _coerce_tracer(trace)
         self.metrics = _coerce_metrics(metrics)
-        self.last_trace = None
         self.last_report = None
+        self._calls = 0
         self._lane = None
         if self.tracer is not None:
             self._lane = self.tracer.vertex("mesh-program")
@@ -568,16 +569,62 @@ class A2AMeshProgram:
                 "devices": self.n_worker, "n_stage": 1,
                 "n_worker": self.n_worker})
 
+    @property
+    def last_trace(self):
+        """The program's lane as it stands (``trace=`` on), else None."""
+        return self.tracer.trace() if self.tracer is not None else None
+
     def _bucket_rows(self, n: int) -> int:
         rows = max(-(-n // self.n_worker), 1, self.block)
         return 1 << (rows - 1).bit_length()
 
     def __call__(self, items: Any) -> List[Tuple[int, Any]]:
+        """One round trip: pack on the host, run, wait for the result and
+        unpack it.  Under JAX's profiler each phase is an ``obs`` region
+        (``mesh.pack``, ``mesh.dispatch`` with ``mesh.compile`` inside on
+        a program's first run, ``mesh.fetch``, ``mesh.unpack``) inside
+        ``mesh.call``, all carrying the call's sequence id."""
+        import numpy as np
+
+        seq = self._calls
+        self._calls += 1
+        t0 = time.monotonic()
+        with _obs.region("mesh.call", seq):
+            with _obs.region("mesh.pack", seq):
+                padded, n, rows = self._pack(items)
+            if padded is None:
+                return []
+            with _obs.region("mesh.dispatch", seq):
+                acc, cnt = self._run(padded, rows, seq)
+            with _obs.region("mesh.fetch", seq):
+                acc = np.asarray(acc)[0]      # waits for the device
+                cnt = np.asarray(cnt)[0]
+            with _obs.region("mesh.unpack", seq):
+                out = [(int(k), acc[k].item()) for k in range(self.nkeys)
+                       if cnt[k] > 0]
+        t1 = time.monotonic()
+        if self._lane is not None:
+            self._lane.span("call", t0, t1, {"items": n, "rows": rows})
+        if self.metrics is not None:
+            reg = self.metrics
+            reg.counter("mesh.calls").inc()
+            reg.counter("mesh.items").inc(n)
+            reg.gauge("mesh.devices").set(self.n_worker)
+            reg.histogram("mesh.call_us").observe((t1 - t0) * 1e6)
+            self.last_report = reg.finalize(reg.report(meta={
+                "backend": "mesh", "items_in": n, "rows": rows,
+                "wall_s": t1 - t0}))
+        return out
+
+    def _pack(self, items: Any):
+        """Items to the padded ``(workers * rows, 2)`` payload-and-flag
+        array, after the dtype and key-range checks; ``(None, 0, 0)`` for
+        an empty stream."""
         import numpy as np
 
         xs = list(items)
         if not xs:
-            return []
+            return None, 0, 0
         arr = np.asarray(xs)
         if arr.dtype.kind == "f":
             arr = arr.astype(np.float32)
@@ -616,33 +663,30 @@ class A2AMeshProgram:
         padded = np.zeros((self.n_worker * rows, 2), arr.dtype)
         padded[:n, 0] = arr
         padded[:n, 1] = 1  # validity flag: padding rows never reduce
-        prog = self._program(rows, str(arr.dtype))
+        return padded, n, rows
+
+    def _run(self, padded, rows: int, seq: int):
+        """Dispatch ``padded`` to its bucket's program.  A program's first
+        run traces and compiles it: that run is the ``compile`` span and
+        the ``mesh.compile`` region, and counts in ``mesh.compiles``."""
+        key = (rows, str(padded.dtype))
+        prog = self._programs.get(key)
+        if prog is not None:
+            return prog(padded)
+        prog = self._programs[key] = self._program(rows)
         t0 = time.monotonic()
-        acc, cnt = prog(padded)
-        t1 = time.monotonic()
+        with _obs.region("mesh.compile", seq):
+            out = prog(padded)
         if self._lane is not None:
-            self._lane.span("call", t0, t1, {"items": n, "rows": rows})
-            self.last_trace = self.tracer.trace()
+            self._lane.span("compile", t0, time.monotonic(),
+                            {"rows": rows, "dtype": key[1]})
         if self.metrics is not None:
-            reg = self.metrics
-            reg.counter("mesh.calls").inc()
-            reg.counter("mesh.items").inc(n)
-            reg.gauge("mesh.devices").set(self.n_worker)
-            reg.histogram("mesh.call_us").observe((t1 - t0) * 1e6)
-            self.last_report = reg.finalize(reg.report(meta={
-                "backend": "mesh", "items_in": n, "rows": rows,
-                "wall_s": t1 - t0}))
-        acc = np.asarray(acc)[0]
-        cnt = np.asarray(cnt)[0]
-        return [(int(k), acc[k].item()) for k in range(self.nkeys)
-                if cnt[k] > 0]
+            self.metrics.counter("mesh.compiles").inc()
+        return out
 
-    def _program(self, rows: int, dtype: str) -> Callable:
-        key = (rows, dtype)
-        if key in self._programs:
-            return self._programs[key]
-        t_compile = time.monotonic()
-
+    def _program(self, rows: int) -> Callable:
+        """The ``shard_map`` program for ``rows`` rows a worker (lazy:
+        ``jax.jit`` compiles it on its first run)."""
         import jax
         import jax.numpy as jnp
         from jax import lax
@@ -693,14 +737,7 @@ class A2AMeshProgram:
             cnt = compat.vma_align(cnt[None, :], (WORKER_AXIS,))
             return acc, cnt
 
-        fn = jax.jit(compat.shard_map(
+        return jax.jit(compat.shard_map(
             body, mesh=self.mesh, in_specs=(P(WORKER_AXIS),),
             out_specs=(P(WORKER_AXIS), P(WORKER_AXIS)),
             check_vma=self.check_vma))
-        if self._lane is not None:
-            self._lane.span("compile", t_compile, time.monotonic(),
-                            {"rows": rows, "dtype": dtype})
-        if self.metrics is not None:
-            self.metrics.counter("mesh.compiles").inc()
-        self._programs[key] = fn
-        return fn

@@ -69,6 +69,7 @@ import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple, Type
 
+from . import obs as _obs
 from .obs import qualname as _qualname
 from .sched import Scheduler, make_scheduler
 from .skeleton import (GO_ON, AllToAll, EmitMany, Farm, FarmStats, Feedback,
@@ -153,6 +154,8 @@ class Vertex:
     def _run(self) -> None:
         tr = self.tracer
         t_birth = time.monotonic() if tr is not None else 0.0
+        if tr is not None:
+            _obs.bind_thread(tr)  # regions in svc record into this lane
         try:
             if self.node is not None:
                 if tr is not None and getattr(self.node, "wants_tracer",
@@ -653,7 +656,9 @@ class WorkerVertex(Vertex):
                 spins = 0
             if tok is EOS:
                 return
-            tb = tr.begin() if tr is not None else 0.0
+            if tr is not None:
+                tr.seq = tok.tag          # the item's id, for its regions
+                tb = tr.begin()
             if record:
                 t0 = time.monotonic()
                 result = self.node.svc(tok.payload)
@@ -838,6 +843,10 @@ class Graph:
     def run(self) -> "Graph":
         assert not self._threads, "graph already running"
         tr = self.tracer
+        if tr is None and _obs.profiling():
+            # under JAX's profiler every vertex records, unsampled, into
+            # the process-wide record: checked once a run, never per item
+            tr = _obs.process_tracer()
         if tr is not None:
             for v in self.vertices:
                 if v.tracer is None:

@@ -39,20 +39,35 @@ lowering shares, built around that constraint:
     report so ``Profile.diff`` (the ROADMAP's online re-tuning seam) can
     compare live runs against a saved pilot.
 
+Program regions (:func:`region`)
+    Named spans around the host work of the device paths (a kernel's
+    dispatch, a mesh program's pack, fetch and unpack) that record only
+    while JAX's profiler traces.  Each records into the calling thread's
+    lane of the process-wide record (:func:`process_tracer`) — a graph
+    vertex's own lane in a vertex thread — with its parent (the span
+    open on that lane) and a sequence id, and opens a
+    ``jax.profiler.TraceAnnotation`` of the same name, so the span also
+    lands in the profiler's trace beside the device's ops.  Off, a region
+    costs one ``TraceAnnotation.is_enabled()`` check.
+
 Everything here is stdlib-only: no jax, no numpy — the module is safe
 in the eager ``repro.core`` import set and the ~0.1s spawn-import
-budget (pinned in ``tests/test_lazy_import.py``).
+budget (pinned in ``tests/test_lazy_import.py``).  The profiler is
+looked up in ``sys.modules``, never imported.
 """
 from __future__ import annotations
 
 import json
 import os
+import sys
+import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 __all__ = [
     "Tracer", "VertexTracer", "Trace", "MetricsRegistry", "Counter",
     "Gauge", "Histogram", "RunReport", "qualname", "farm_stats_snapshot",
+    "region", "profiling", "process_tracer", "bind_thread",
 ]
 
 #: event-kind vocabulary (the typed part of "typed events"); spans and
@@ -87,8 +102,14 @@ class VertexTracer:
     ``monotonic()`` reads and one tuple append.  ``instant()`` records
     rare events (steal/spill/eos) unsampled; ``tick()`` is the sampled
     instant for high-frequency ones (loop tokens).  The buffer is a
-    plain list capped at ``capacity`` — overflow increments ``dropped``
-    instead of growing, so a runaway vertex cannot eat the heap.
+    plain list capped at ``capacity``: overflow drops the older half and
+    counts it in ``dropped``, so a runaway vertex cannot eat the heap and
+    the newest events (a run's end, the latest profiler window) survive.
+
+    ``opened`` holds the start stamps of the spans open on the lane
+    (sampled-in ``begin()`` calls and regions), so a region records
+    its parent; ``seq`` is the sequence id of the item the lane is
+    serving, which a region inherits when it names none.
 
     Events are plain tuples ``(kind, t0, t1)`` (``t1 is None`` for an
     instant, optionally ``(kind, t0, t1, args)``), picklable as-is for
@@ -96,7 +117,7 @@ class VertexTracer:
     """
 
     __slots__ = ("name", "path", "pid", "capacity", "events", "dropped",
-                 "_n", "_mask")
+                 "opened", "seq", "_n", "_mask")
 
     def __init__(self, name: str, path: str = "", *, sample: int = 16,
                  capacity: int = 2048, pid: Optional[int] = None):
@@ -106,6 +127,8 @@ class VertexTracer:
         self.capacity = int(capacity)
         self.events: List[tuple] = []
         self.dropped = 0
+        self.opened: List[float] = []
+        self.seq: Any = None
         self._n = 0
         self._mask = _pow2(sample) - 1
 
@@ -121,16 +144,19 @@ class VertexTracer:
         self._n = n + 1
         if n & self._mask:
             return 0.0
-        return _monotonic()
+        t0 = _monotonic()
+        self.opened.append(t0)
+        return t0
 
     def end(self, t0: float, kind: str) -> None:
         """Close the span opened by the matching :meth:`begin`."""
         if not t0:
             return
+        self._close(t0)
         if len(self.events) < self.capacity:
             self.events.append((kind, t0, _monotonic()))
         else:
-            self.dropped += 1
+            self._overflow((kind, t0, _monotonic()))
 
     def tick(self, kind: str) -> None:
         """Sampled instant — for per-item-frequency events (loop tokens);
@@ -142,30 +168,39 @@ class VertexTracer:
         if len(self.events) < self.capacity:
             self.events.append((kind, _monotonic(), None))
         else:
-            self.dropped += 1
+            self._overflow((kind, _monotonic(), None))
 
     # -- the rare path -------------------------------------------------------
     def instant(self, kind: str, args: Optional[dict] = None) -> None:
         """Unsampled instant — for rare events (steal, spill, EOS)."""
+        event = (kind, _monotonic(), None) if args is None else \
+            (kind, _monotonic(), None, args)
         if len(self.events) < self.capacity:
-            if args is None:
-                self.events.append((kind, _monotonic(), None))
-            else:
-                self.events.append((kind, _monotonic(), None, args))
+            self.events.append(event)
         else:
-            self.dropped += 1
+            self._overflow(event)
 
     def span(self, kind: str, t0: float, t1: float,
              args: Optional[dict] = None) -> None:
         """Unsampled span with caller-supplied stamps — program-level
         events (mesh compile/call walls) and already-timed stalls."""
+        event = (kind, t0, t1) if args is None else (kind, t0, t1, args)
         if len(self.events) < self.capacity:
-            if args is None:
-                self.events.append((kind, t0, t1))
-            else:
-                self.events.append((kind, t0, t1, args))
+            self.events.append(event)
         else:
-            self.dropped += 1
+            self._overflow(event)
+
+    def _close(self, t0: float) -> None:
+        opened = self.opened
+        if opened and opened[-1] == t0:
+            opened.pop()
+
+    def _overflow(self, event: tuple) -> None:
+        """The lane is full: drop the older half, keep ``event``."""
+        cut = max(1, len(self.events) // 2)
+        del self.events[:cut]
+        self.dropped += cut
+        self.events.append(event)
 
 
 class Tracer:
@@ -272,6 +307,122 @@ class Trace:
             with open(path, "w") as f:
                 json.dump(doc, f)
         return doc
+
+
+# ---------------------------------------------------------------------------
+# program regions: spans that switch on with JAX's profiler
+# ---------------------------------------------------------------------------
+#: per-lane bound of the process-wide record: a search worker writes two
+#: events a pair (its ``svc`` and its kernel dispatch), about 24k in a 30 s
+#: window and its drain, and the busiest vertex under 50k
+REGION_CAPACITY = 1 << 17
+
+_process = Tracer(sample=1, capacity=REGION_CAPACITY)
+_local = threading.local()
+_annotation: Any = None        # jax.profiler.TraceAnnotation, once imported
+
+
+def profiling() -> bool:
+    """Whether JAX's profiler is tracing.  Never imports jax: until some
+    other module has imported ``jax.profiler`` the answer is ``False``."""
+    ann = _annotation
+    if ann is None:
+        ann = _find_annotation()
+        if ann is None:
+            return False
+    return ann.is_enabled()
+
+
+def _find_annotation() -> Any:
+    global _annotation
+    _annotation = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation",
+                          None)
+    return _annotation
+
+
+def process_tracer() -> Tracer:
+    """The process-wide record: unsampled lanes that regions (and, under
+    the profiler, ``Graph.run``'s vertices) write into.  Each lane keeps
+    its newest :data:`REGION_CAPACITY` events, so a reader takes a
+    window's spans by their stamps."""
+    return _process
+
+
+def bind_thread(lane: VertexTracer) -> None:
+    """Make ``lane`` the calling thread's: a graph vertex binds its own
+    lane, so regions inside its ``svc`` stay single-writer and nest under
+    the ``svc`` span."""
+    _local.lane = lane
+
+
+def _thread_lane() -> VertexTracer:
+    lane = getattr(_local, "lane", None)
+    if lane is None:
+        lane = _local.lane = _process.vertex(threading.current_thread().name)
+    return lane
+
+
+class _Off:
+    """The region handed out while the profiler is off: shared, inert."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_Off":
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        return False
+
+
+_OFF = _Off()
+
+
+class _Region:
+    __slots__ = ("name", "seq", "lane", "parent", "t0", "twin")
+
+    def __init__(self, name: str, seq: Any):
+        if name.startswith("bench."):
+            raise ValueError(f"region {name!r}: 'bench.' names are the "
+                             f"benchmark harness's own spans")
+        self.name = name
+        self.seq = seq
+
+    def __enter__(self) -> "_Region":
+        lane = self.lane = _thread_lane()
+        opened = lane.opened
+        self.parent = opened[-1] if opened else None
+        self.twin = _annotation(self.name)
+        self.twin.__enter__()
+        self.t0 = t0 = _monotonic()
+        opened.append(t0)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        t1 = _monotonic()
+        self.twin.__exit__(exc_type, exc, tb)
+        lane = self.lane
+        lane._close(self.t0)
+        seq = lane.seq if self.seq is None else self.seq
+        lane.span(self.name, self.t0, t1, {"parent": self.parent, "seq": seq})
+        return False
+
+
+def region(name: str, seq: Any = None):
+    """A span of host work, recorded only while JAX's profiler traces::
+
+        with obs.region("mesh.pack", seq=call):
+            ...
+
+    On, it records ``(name, t0, t1, {"parent", "seq"})`` into the calling
+    thread's lane on the ``time.monotonic()`` clock — ``parent`` is the
+    start stamp of the span open on that lane, ``seq`` the given id or
+    the lane's current item — and opens a ``TraceAnnotation`` twin of the
+    same name in the profiler's trace.  Off, it is one check and a shared
+    no-op.  Names starting ``bench.`` are refused: the benchmark's trace
+    reducer keeps those for its own spans."""
+    if not profiling():
+        return _OFF
+    return _Region(name, seq)
 
 
 # ---------------------------------------------------------------------------

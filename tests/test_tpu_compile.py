@@ -9,12 +9,14 @@ never at import time: only one process at a time may load the TPU
 library, and every test worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.smith_waterman import sw_pallas
 from repro.kernels.ssd_scan import ssd_scan
@@ -65,6 +67,16 @@ def test_sw_pallas_compiles_for_v5e(shape, q_len):
         gap_open=10.0, gap_extend=2.0, q_len=q_len, tile=512,
         interpret=False).compile()
     _assert_mosaic(compiled)
+
+
+def test_sw_kernel_op_keeps_the_name_the_benchmark_matches(shape):
+    """``sw.kernel_gcups`` finds the kernel's device time by this op name:
+    the Pallas custom call inherits the jitted ``sw_pallas`` wrapper's."""
+    compiled = ops.smith_waterman_padded.lower(
+        shape((1000,), jnp.int32), shape((1024,), jnp.int32),
+        shape((24, 24), jnp.float32), gap_open=10.0, gap_extend=2.0,
+        tile=512, interpret=False).compile()
+    assert re.search(r"%sw_pallas[.\d]* = ", compiled.as_text())
 
 
 def test_flash_attention_compiles_for_v5e_at_phi3_mini_width(shape):
