@@ -526,6 +526,12 @@ class A2AMeshProgram:
 
     Static key space required: ``reduce.nkeys`` bounds the segment arrays,
     and ``by`` must yield integer keys in ``[0, nkeys)``.
+
+    Input: an object whose type offers ``__array__`` (a numpy array, a JAX
+    array) packs as one array, never item by item, and is not written to;
+    it counts in ``mesh.pack_array``.  Any other iterable (a list,
+    ``range``, a generator) is gathered into a list first.  Both paths run
+    the same checks and give the same result.
     """
 
     backend = "mesh"
@@ -591,23 +597,26 @@ class A2AMeshProgram:
         t0 = time.monotonic()
         with _obs.region("mesh.call", seq):
             with _obs.region("mesh.pack", seq):
-                padded, n, rows = self._pack(items)
+                padded, n, rows, as_array = self._pack(items)
             if padded is None:
                 return []
             with _obs.region("mesh.dispatch", seq):
                 acc, cnt = self._run(padded, rows, seq)
             with _obs.region("mesh.fetch", seq):
-                acc = np.asarray(acc)[0]      # waits for the device
-                cnt = np.asarray(cnt)[0]
+                cnt = np.asarray(cnt)[0]      # waits for the device
+                # a count's fold is its count: one fetch, not two
+                acc = cnt if self.kind == "count" else np.asarray(acc)[0]
             with _obs.region("mesh.unpack", seq):
-                out = [(int(k), acc[k].item()) for k in range(self.nkeys)
-                       if cnt[k] > 0]
+                live = np.flatnonzero(cnt > 0)
+                out = list(zip(live.tolist(), acc[live].tolist()))
         t1 = time.monotonic()
         if self._lane is not None:
             self._lane.span("call", t0, t1, {"items": n, "rows": rows})
         if self.metrics is not None:
             reg = self.metrics
             reg.counter("mesh.calls").inc()
+            if as_array:
+                reg.counter("mesh.pack_array").inc()
             reg.counter("mesh.items").inc(n)
             reg.gauge("mesh.devices").set(self.n_worker)
             reg.histogram("mesh.call_us").observe((t1 - t0) * 1e6)
@@ -618,19 +627,22 @@ class A2AMeshProgram:
 
     def _pack(self, items: Any):
         """Items to the padded ``(workers * rows, 2)`` payload-and-flag
-        array, after the dtype and key-range checks; ``(None, 0, 0)`` for
-        an empty stream."""
+        array, after the dtype and key-range checks, with its item count,
+        rows a worker and whether ``items`` packed as one array;
+        ``(None, 0, 0, ...)`` for an empty stream."""
         import numpy as np
 
-        xs = list(items)
-        if not xs:
-            return None, 0, 0
-        arr = np.asarray(xs)
+        as_array = hasattr(type(items), "__array__")
+        arr = np.asarray(items if as_array else list(items))
+        if arr.ndim and not arr.shape[0]:
+            return None, 0, 0, as_array
+        # astype copies, so the checks and pre-maps below never touch the
+        # caller's array
         if arr.dtype.kind == "f":
             arr = arr.astype(np.float32)
         elif arr.dtype.kind in "iub":
             cast = arr.astype(np.int32)
-            if not np.array_equal(cast, arr):
+            if arr.dtype != np.int32 and not np.array_equal(cast, arr):
                 raise LoweringError(
                     "integer payloads exceed int32 (the mesh compute "
                     "dtype); the host backends fold exact Python ints — "
@@ -663,7 +675,7 @@ class A2AMeshProgram:
         padded = np.zeros((self.n_worker * rows, 2), arr.dtype)
         padded[:n, 0] = arr
         padded[:n, 1] = 1  # validity flag: padding rows never reduce
-        return padded, n, rows
+        return padded, n, rows, as_array
 
     def _run(self, padded, rows: int, seq: int):
         """Dispatch ``padded`` to its bucket's program.  A program's first

@@ -402,3 +402,92 @@ def test_three_backend_parity_with_batched_zero_copy_procs():
     assert dict(lower(RBK, "threads")(xs)) == want
     assert dict(lower(RBK, "procs", batch=8, zero_copy=True)(xs)) == want
     assert dict(lower(RBK, "mesh")(xs)) == want
+
+
+# -- mesh pack: an array packs whole, with the list path's checks and output --
+def double_in_place(x):
+    # rebinds a traced JAX array, writes a numpy column in place: the
+    # pack must hand the pre-maps its own copy, never the caller's array
+    x *= 2
+    return x
+
+
+def _typed(out):
+    return [(type(k), type(v)) for k, v in out]
+
+
+@pytest.mark.parametrize("premap", [False, True], ids=["by", "premap"])
+@pytest.mark.parametrize("dtype", [np.int32, np.float32])
+@pytest.mark.parametrize("fold", ["count", "sum", "min", "max"])
+def test_mesh_array_path_matches_list_path(fold, dtype, premap):
+    """A numpy array and its list form give the identical pairs: same keys
+    in the same ascending order, same values, same Python types; key 7
+    never occurs and is left out."""
+    rbk = reduce_by_key(N.mod7, fold, nkeys=8)
+    prog = lower(Pipeline(Stage(N.double), rbk) if premap else rbk, "mesh")
+    xs = (np.random.default_rng(7).integers(-40, 900, 301) *
+          (0.25 if dtype is np.float32 else 1)).astype(dtype)
+    got, want = prog(xs), prog(xs.tolist())
+    assert got == want and _typed(got) == _typed(want)
+    vtype = int if fold == "count" or dtype is np.int32 else float
+    assert set(_typed(got)) == {(int, vtype)}
+    assert [k for k, _ in got] == sorted(k for k, _ in got)
+    assert set(dict(got)) == {int(N.mod7(2 * x if premap else x))
+                              for x in xs.tolist()}
+
+
+@pytest.mark.parametrize("case", ["2d", "int64_range", "key_range"])
+def test_mesh_array_path_raises_as_list_path(case):
+    """Each refusal reads the same for an array and for its list form."""
+    skel = reduce_by_key(N.mod5, "sum", nkeys=5)
+    if case == "2d":
+        xs = np.arange(12, dtype=np.int32).reshape(6, 2)
+        lst = xs.tolist()
+    elif case == "int64_range":
+        xs = np.array([1, 2, 1 << 40], np.int64)
+        lst = xs.tolist()
+    else:
+        skel = reduce_by_key(N.mod7, "sum", nkeys=5)
+        xs = np.arange(35, dtype=np.int32)
+        lst = range(35)
+    prog = lower(skel, "mesh")
+    with pytest.raises(LoweringError) as from_array:
+        prog(xs)
+    with pytest.raises(LoweringError) as from_list:
+        prog(lst)
+    assert str(from_array.value) == str(from_list.value)
+
+
+@pytest.mark.parametrize("xs", [np.zeros(0, np.int32), np.zeros(0, np.float32),
+                                np.zeros((0, 1), np.int32)],
+                         ids=["int32", "float32", "2d"])
+def test_mesh_empty_array_returns_empty(xs):
+    assert RBK_M(xs) == RBK_M(list(xs)) == []
+
+
+@pytest.mark.parametrize("dtype", [np.int32, np.float32, np.int64])
+def test_mesh_array_path_leaves_callers_array(dtype):
+    prog = lower(Pipeline(Stage(double_in_place),
+                          reduce_by_key(N.mod5, "sum", nkeys=5)), "mesh")
+    xs = np.arange(40).astype(dtype)
+    before = xs.copy()
+    out = prog(xs)
+    np.testing.assert_array_equal(xs, before)
+    assert xs.dtype == before.dtype
+    assert out == prog(before.tolist())
+
+
+def test_mesh_pack_array_counts_array_calls_only():
+    """``mesh.pack_array`` counts the calls that packed an array (numpy or
+    JAX), not those fed a list, a range or a generator."""
+    import jax.numpy as jnp
+
+    prog = lower(reduce_by_key(N.mod5, "count", nkeys=5), "mesh",
+                 metrics=True)
+    xs = np.arange(23, dtype=np.int32)
+    want = prog(list(xs))
+    assert prog(range(23)) == prog(x for x in range(23)) == want
+    assert prog.metrics.counter("mesh.pack_array").value == 0
+    assert prog(xs) == prog(jnp.asarray(xs)) == want
+    assert prog.metrics.counter("mesh.pack_array").value == 2
+    assert prog.metrics.counter("mesh.calls").value == 5
