@@ -185,12 +185,12 @@ def phase_serve(cfg, max_batch: int = SERVE_BATCH,
 
     step, finite, step_s = eng._step, [], []
 
-    def checked_step(params, batch, cache, cache_len):
+    def checked_step(params, batch, cache, positions):
         t = time.perf_counter()
-        logits, cache = step(params, batch, cache, cache_len)
+        nxt, logits, cache = step(params, batch, cache, positions)
         finite.append(bool(jnp.isfinite(logits).all()))
         step_s.append(time.perf_counter() - t)
-        return logits, cache
+        return nxt, logits, cache
 
     eng._step = checked_step
     rng = np.random.default_rng(SEED)

@@ -245,7 +245,8 @@ def main():
 
     cache = init_cache(cfg, batch=2, max_len=16)
     logits, cache = jax.jit(lambda p, b, c, l: decode_step(p, b, c, l, cfg))(
-        params, {"tokens": jnp.zeros((2, 1), jnp.int32)}, cache, jnp.int32(0))
+        params, {"tokens": jnp.zeros((2, 1), jnp.int32)}, cache,
+        jnp.zeros((2,), jnp.int32))
     print("decode logits:", logits.shape)
     print("quickstart OK")
 

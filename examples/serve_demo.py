@@ -2,7 +2,7 @@
 
 Requests with different prompt lengths arrive while earlier ones are still
 decoding; the admitter (Emitter) recycles batch slots through the SPMC page
-pool, per-slot start offsets isolate requests, and the collector emits
+pool, a cache position per slot isolates requests, and the collector emits
 results in submission order.  Under the hood ``ServeEngine.run`` is now a
 skeleton expression — ``Source(requests) ∘ Farm(decode_step,
 feedback=still_generating)`` — lowered to the thread graph; the decode tick

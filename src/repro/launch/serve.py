@@ -25,16 +25,24 @@ the engine's old termination condition, now provided by the runtime.  One
 tick = one jitted decode step advancing the whole continuous batch, so the
 batching behaviour (and ``steps_run`` accounting) is unchanged.
 
-Requests are admitted into recycled slots mid-stream; per-slot ``start_pos``
-masks each request's attention to its own KV span.  Prompt ingestion is
-token-by-token (one decode step per prompt token), which keeps one jitted
-step for everything; a batched prefill path is the obvious production
-extension and exists as ``steps.make_prefill_step`` for the dry-run.
+Requests are admitted into recycled slots mid-stream.  Each slot has its
+own cache position: 0 at admission, one more for every step the slot is
+occupied, so a request's tokens sit at their own positions and its
+attention reads only what it wrote.  Prompt ingestion is token-by-token
+(one decode step per prompt token), which keeps one jitted step for
+everything; the step that feeds a prompt's last token yields its first
+output token, so a request with a prompt of P tokens and ``max_new`` N
+occupies its slot for P + N - 1 steps.  Each step is dispatched before
+the previous step's tokens are fetched (a generating slot feeds its last
+token from the device), so the host's bookkeeping overlaps the chip's
+step.  A batched prefill path is the obvious production extension and
+exists as ``steps.make_prefill_step`` for the dry-run.
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import time
 from collections import deque
 from typing import Dict, List, Optional
@@ -44,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs import ARCHS
+from ..core import obs
 from ..core.allocator import PagePool
 from ..core.obs import MetricsRegistry, Tracer
 from ..core.sched import CostModel
@@ -51,9 +60,10 @@ from ..core.skeleton import Farm, Source, compose, lower
 from ..core.spsc import SPSCQueue
 from ..models import decode_step as model_decode, init_cache, init_params
 from ..models.config import ModelConfig
+from ..models.model import reset_cache_slot
 from ..runtime.compile_cache import enable_compile_cache
 
-__all__ = ["Request", "ServeEngine"]
+__all__ = ["Request", "ServeEngine", "serve_step"]
 
 _TICK = object()  # the decode-tick token circulating the wrap-around ring
 
@@ -68,9 +78,25 @@ class Request:
     submitted: float = 0.0  # monotonic submit() timestamp (latency origin)
     tag: int = -1
     slot: int = -1
-    start: int = -1
+    start: int = -1  # engine step that admitted it (fed its position 0)
     generated: List[int] = dataclasses.field(default_factory=list)
-    fed: int = 0  # prompt tokens consumed
+    fed: int = 0  # tokens fed so far: its slot's cache position
+    asked: int = 0  # steps dispatched that yield one of its tokens
+    # the float32 logits row behind each generated token, kept when the
+    # engine's ``keep_logits`` holds ``rid``
+    logits: List[np.ndarray] = dataclasses.field(default_factory=list)
+
+
+def serve_step(params, batch, cache, positions, cfg: ModelConfig):
+    """The engine's decode program: one token for every slot at its own
+    position.  A row fed -1 feeds ``batch["last"]`` instead, the token
+    the previous step chose for it, which stays on the device.  Returns
+    (greedy next tokens (B,), logits, new cache)."""
+    batch = dict(batch)
+    tokens, last = batch["tokens"], batch.pop("last")
+    batch["tokens"] = jnp.where(tokens < 0, last[:, None], tokens)
+    logits, cache = model_decode(params, batch, cache, positions, cfg)
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits, cache
 
 
 class ServeEngine:
@@ -80,7 +106,22 @@ class ServeEngine:
     checked; alerts land in ``slo.events``, in the registry's
     ``slo.alerts`` counter (and its ``watch()`` callbacks), and as
     ``alert`` instants on an ``slo-monitor`` trace lane
-    (``engine.last_trace``), time-aligned with the run."""
+    (``engine.last_trace``), time-aligned with the run.
+
+    ``keep_logits`` is a set of request ids whose served logits rows the
+    engine keeps (``Request.logits``), fetched in the same transfer as the
+    step's tokens: the seam through which served logits are compared
+    with a reference.
+
+    Each step runs inside an ``obs.region("serve.step", seq=step)`` that
+    holds ``serve.admit``, ``serve.dispatch``, ``serve.sync`` (the wait
+    for the previous step's tokens) and ``serve.collect``; the registry
+    counts the slot-steps that fed a prompt token and yielded nothing
+    (``serve.prompt_slot_steps``) and that held no request
+    (``serve.free_slot_steps``) as it dispatches a step, and the tokens
+    it hands to requests (``serve.gen_slot_steps``) as it collects one;
+    the row of a step dispatched after a request's end token is counted
+    by none."""
 
     def __init__(self, cfg: ModelConfig, *, max_batch: int = 4,
                  max_len: int = 256, seed: int = 0, params=None,
@@ -101,14 +142,24 @@ class ServeEngine:
         self.done: Dict[int, Request] = {}         # tag -> finished request
         self.emit_next = 0
         self.results: List[Request] = []
-        self.cache_len = 0
+        self.keep_logits: set = set()
         self.tag_counter = 0
-        self._step = jax.jit(
-            lambda p, b, c, l: model_decode(p, b, c, l, cfg),
-            donate_argnums=(2,))
+        self._step = jax.jit(functools.partial(serve_step, cfg=cfg),
+                             donate_argnums=(2,))
+        # recurrent state must start from zero in a recycled slot; K/V need
+        # no reset (a row reads only positions it has written)
+        self._reset = None if set(self.cache) <= {"k", "v"} else jax.jit(
+            lambda c, slot: reset_cache_slot(c, slot, cfg), donate_argnums=0)
         self.steps_run = 0
+        # the last step's tokens, on the device: a generating request was
+        # fed in that step, so its latest token is there
+        self._last = jnp.zeros((max_batch,), jnp.int32)
+        self._inflight = None      # (tokens, logits, fed) not yet collected
         self.metrics = MetricsRegistry()
         self._latency = self.metrics.histogram("serve.request_latency_us")
+        self._prompt_steps = self.metrics.counter("serve.prompt_slot_steps")
+        self._gen_steps = self.metrics.counter("serve.gen_slot_steps")
+        self._free_steps = self.metrics.counter("serve.free_slot_steps")
         self.last_report = None
         self.slo = slo
         self.tracer = None
@@ -121,6 +172,16 @@ class ServeEngine:
 
     # -- emitter side --------------------------------------------------------
     def submit(self, req: Request) -> None:
+        """Queue ``req``; refused (``ValueError``) where its prompt is empty,
+        it asks for no token, or its P + ``max_new`` tokens do not fit a
+        slot's ``max_len`` positions."""
+        if not req.prompt or req.max_new < 1:
+            raise ValueError(f"request {req.rid}: needs a prompt and "
+                             f"max_new >= 1")
+        if len(req.prompt) + req.max_new > self.max_len:
+            raise ValueError(
+                f"request {req.rid}: {len(req.prompt)} prompt + "
+                f"{req.max_new} new tokens exceed max_len={self.max_len}")
         if req.submitted == 0.0:
             req.submitted = time.monotonic()
         self.in_q.push_wait(req)
@@ -137,73 +198,106 @@ class ServeEngine:
             nxt.tag = self.tag_counter
             self.tag_counter += 1
             nxt.slot = slot
-            nxt.start = self.cache_len
-            self._reset_slot(slot)
+            nxt.start = self.steps_run
+            if self._reset is not None:
+                self.cache = self._reset(self.cache, jnp.int32(slot))
             self.active[slot] = nxt
 
-    def _reset_slot(self, slot: int) -> None:
-        """Zero the recycled slot's cache state (SSM state must reset;
-        attention K/V is masked by start_pos, zeroing is belt-and-braces)."""
-        def z(leaf):
-            if leaf.ndim >= 2 and leaf.shape[-4:-3] != ():  # kv caches (.., B, T, H, D)
-                pass
-            return leaf
+    @staticmethod
+    def _feed(req: Request):
+        """The token ``req`` feeds this step (``None``: its latest output
+        token), and whether the step yields its next output token: it
+        does from its last prompt token on."""
+        p, n = req.fed, len(req.prompt)
+        return (req.prompt[p] if p < n else None), p >= n - 1
 
-        def zero_slot(leaf):
-            # batch dim position differs per leaf family; all our cache
-            # leaves carry batch at axis -4 (kv: L,B,T,H,D) or -3/-2 (ssm)
-            for ax in range(leaf.ndim):
-                if leaf.shape[ax] == self.max_batch:
-                    idx = [slice(None)] * leaf.ndim
-                    idx[ax] = slot
-                    return leaf.at[tuple(idx)].set(0)
-            return leaf
-
-        self.cache = jax.tree.map(zero_slot, self.cache)
+    @staticmethod
+    def _finished(req: Request) -> bool:
+        g = req.generated
+        return len(g) >= req.max_new or (
+            req.eos_id is not None and bool(g) and g[-1] == req.eos_id)
 
     # -- one farm iteration ----------------------------------------------------
     def step(self) -> None:
-        self._admit()
-        if not self.active:
-            return
-        tokens = np.zeros((self.max_batch, 1), np.int32)
-        start = np.zeros((self.max_batch,), np.int32)
-        for slot, req in self.active.items():
-            if req.fed < len(req.prompt):
-                tokens[slot, 0] = req.prompt[req.fed]
+        """Dispatch one decode step, then collect the step before it.
+
+        The host never waits for a step before dispatching the next: a
+        generating slot is fed -1, for the token the previous step chose
+        for it, still on the device, so admission, the batch and the
+        collector run while the chip computes.  A request leaves its
+        slot when the step that yields its last token is dispatched; one
+        that ends on ``eos_id`` is seen a step later, and the row of that
+        extra step is dropped."""
+        with obs.region("serve.step", seq=self.steps_run):
+            with obs.region("serve.admit"):
+                self._admit()
+            if not self.active:
+                self._drain()
+                return
+            if self.cfg.family == "audio":
+                raise NotImplementedError("audio serving uses frame embeddings")
+            with obs.region("serve.dispatch"):
+                # free slots feed a pad at position 0, and their row is
+                # ignored
+                tokens = np.zeros((self.max_batch, 1), np.int32)
+                pos = np.zeros((self.max_batch,), np.int32)
+                fed = []
+                for slot, req in list(self.active.items()):
+                    tok, yields = self._feed(req)
+                    tokens[slot, 0] = -1 if tok is None else tok
+                    pos[slot] = req.fed
+                    req.fed += 1
+                    fed.append((slot, req, yields))
+                    req.asked += yields
+                    if req.asked >= req.max_new:
+                        del self.active[slot]      # its last token is asked
+                        self.pool.free(slot, 0)
+                nxt, logits, self.cache = self._step(
+                    self.params, {"tokens": tokens, "last": self._last},
+                    self.cache, pos)
+            self.steps_run += 1
+            self._prompt_steps.inc(sum(not y for _, _, y in fed))
+            self._free_steps.inc(self.max_batch - len(fed))
+            prev, self._inflight = self._inflight, (nxt, logits, fed)
+            self._last = nxt
+            if prev is not None:
+                self._collect(prev)
+
+    def _drain(self) -> None:
+        """Collect the step still in flight, if any."""
+        if self._inflight is not None:
+            prev, self._inflight = self._inflight, None
+            self._collect(prev)
+
+    def _collect(self, inflight) -> None:
+        nxt, logits, fed = inflight
+        with obs.region("serve.sync"):
+            rows = None
+            if any(y and req.rid in self.keep_logits for _, req, y in fed):
+                nxt, rows = jax.device_get((nxt, logits))
             else:
-                tokens[slot, 0] = req.generated[-1] if req.generated else 0
-            start[slot] = req.start
-        batch = {"tokens": jnp.asarray(tokens), "start_pos": jnp.asarray(start)}
-        if self.cfg.family == "audio":
-            raise NotImplementedError("audio serving uses frame embeddings")
-        logits, self.cache = self._step(self.params, batch, self.cache,
-                                        jnp.int32(self.cache_len))
-        self.cache_len += 1
-        self.steps_run += 1
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        finished = []
-        for slot, req in self.active.items():
-            if req.fed < len(req.prompt):
-                req.fed += 1          # still ingesting the prompt
-                continue
-            tok = int(nxt[slot])
-            req.generated.append(tok)
-            if (req.eos_id is not None and tok == req.eos_id) or \
-               len(req.generated) >= req.max_new:
-                finished.append(slot)
-        # -- collector: free pages, emit in tag order -------------------------
-        for slot in finished:
-            req = self.active.pop(slot)
-            self.pool.free(slot, 0)
-            self.done[req.tag] = req
-        while self.emit_next in self.done:
-            req = self.done.pop(self.emit_next)
-            if req.submitted:
-                self._latency.observe(
-                    (time.monotonic() - req.submitted) * 1e6)
-            self.results.append(req)
-            self.emit_next += 1
+                nxt = np.asarray(nxt)
+        with obs.region("serve.collect"):
+            for slot, req, yields in fed:
+                if not yields or self._finished(req):
+                    continue      # a prompt token, or a row past its end
+                req.generated.append(int(nxt[slot]))
+                self._gen_steps.inc()
+                if rows is not None and req.rid in self.keep_logits:
+                    req.logits.append(np.array(rows[slot], np.float32))
+                if self._finished(req):
+                    if self.active.get(slot) is req:     # ended on eos_id
+                        del self.active[slot]
+                        self.pool.free(slot, 0)
+                    self.done[req.tag] = req
+            # -- collector: emit in tag order ---------------------------------
+            while self.emit_next in self.done:
+                req = self.done.pop(self.emit_next)
+                if req.submitted:
+                    self._latency.observe(
+                        (time.monotonic() - req.submitted) * 1e6)
+                self.results.append(req)
+                self.emit_next += 1
 
     def _drain_submitted(self) -> List[Request]:
         """Everything submitted so far, in submission order (the stream the
@@ -239,11 +333,11 @@ class ServeEngine:
                 self._pending.append(task)         # admitted on the next tick
                 return ("enq",)
             self._admit()
-            if self.active and self.cache_len < self.max_len and budget[0]:
+            if self.active and budget[0]:
                 budget[0] -= 1
                 self.step()
             more = bool(self.active or self._pending or len(self.in_q)) \
-                and self.cache_len < self.max_len and budget[0] > 0
+                and budget[0] > 0
             return ("tick", more)
 
         tick_in_flight = [False]                   # touched only by the route
@@ -263,7 +357,7 @@ class ServeEngine:
 
         stream: List = self._drain_submitted()
         if self.active or self._pending:
-            # a previous run() was truncated (budget / max_len): seed a
+            # a previous run() was truncated by its budget: seed a
             # tick so the leftover batch resumes without new submissions
             stream.insert(0, _TICK)
         # CostModel placement: the decode worker's per-tick service time
@@ -282,6 +376,7 @@ class ServeEngine:
         prog = lower(net, "threads",
                      trace=self.tracer if self.tracer is not None else False)
         prog.to_graph().run_and_wait()
+        self._drain()
         wall = time.monotonic() - t0
         served = len(self.results) - n_before
         toks = sum(len(r.generated) for r in self.results) - toks_before

@@ -47,8 +47,8 @@ def make_prefill_step(cfg: ModelConfig):
 
 
 def make_decode_step(cfg: ModelConfig):
-    def serve_step(params, batch, cache, cache_len):
-        return M.decode_step(params, batch, cache, cache_len, cfg)
+    def serve_step(params, batch, cache, positions):
+        return M.decode_step(params, batch, cache, positions, cfg)
     return serve_step
 
 
@@ -100,7 +100,7 @@ def input_specs(cfg: ModelConfig, shape, ctx: Optional[MeshCtx]) -> Dict[str, An
     Returns kwargs for the step function of the cell's kind:
       train   → {params, opt_state, batch}
       prefill → {params, batch}
-      decode  → {params, batch, cache, cache_len}
+      decode  → {params, batch, cache, positions}  (a position per row)
     """
     cfg = serve_cfg(cfg, shape, ctx)
     B, S = shape.global_batch, shape.seq_len
@@ -133,7 +133,7 @@ def input_specs(cfg: ModelConfig, shape, ctx: Optional[MeshCtx]) -> Dict[str, An
     cache_tok = M.cache_pspecs(cfg, B, dp_divisible=(B % max(dp, 1) == 0))
     cache = _sds(cache_shapes, cache_tok, ctx)
     return {"params": params, "batch": batch_of(1), "cache": cache,
-            "cache_len": jax.ShapeDtypeStruct((), jnp.int32)}
+            "positions": jax.ShapeDtypeStruct((B,), jnp.int32)}
 
 
 def step_fn_for(cfg: ModelConfig, shape, ctx: Optional[MeshCtx] = None):
@@ -142,4 +142,4 @@ def step_fn_for(cfg: ModelConfig, shape, ctx: Optional[MeshCtx] = None):
         return make_train_step(cfg), ("params", "opt_state", "batch")
     if shape.kind == "prefill":
         return make_prefill_step(cfg), ("params", "batch")
-    return make_decode_step(cfg), ("params", "batch", "cache", "cache_len")
+    return make_decode_step(cfg), ("params", "batch", "cache", "positions")

@@ -151,17 +151,17 @@ def attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
                              causal_skip=causal_skip, q_offset=q_offset)
 
 
-def decode_attention(q, k_cache, v_cache, cache_len, *, window: Optional[int] = None,
-                     rolling: bool = False,
-                     start_pos: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """Single-step attention against a cache.
+def decode_attention(q, k_cache, v_cache, positions) -> jnp.ndarray:
+    """Single-step attention against a cache, masked per row.
 
-    q: (B, 1, H, Dh); caches: (B, T, Hkv, Dh); cache_len: scalar — number of
-    valid entries (the new token's k/v already written).  With
-    ``rolling=True`` the cache is a circular SWA buffer where *all* T slots
-    are valid once full; masking is by slot validity only.
-    ``start_pos`` (B,) masks slots before a request's admission — the
-    continuous-batching farm admits requests into recycled slots mid-stream.
+    q: (B, 1, H, Dh); caches: (B, T, Hkv, Dh); positions: (B,) — row b's
+    new token sits at position ``positions[b]`` (its k/v already written)
+    and attends to cache indices ``<= positions[b]``.  A sliding-window
+    model keeps a rolling cache of T = window slots written at
+    ``pos % T``: once a row has filled it every slot is valid and holds one
+    of its last T positions, so the same mask is its window.  Indices
+    above a row's position hold an earlier request's entries (a recycled
+    slot) or nothing, and are never read.
     """
     B, _, H, Dh = q.shape
     T, Hkv = k_cache.shape[1], k_cache.shape[2]
@@ -173,15 +173,7 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window: Optional[int] = 
     qg = q.reshape(B, 1, Hkv, g, Dh)
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k_cache).astype(jnp.float32)
     s = s * (Dh ** -0.5)
-    slot = jnp.arange(T)
-    if rolling:
-        valid = jnp.broadcast_to(slot < jnp.minimum(cache_len, T), (B, T))
-    else:
-        valid = jnp.broadcast_to(slot < cache_len, (B, T))
-        if window is not None:
-            valid &= slot[None, :] > cache_len - 1 - window
-    if start_pos is not None and not rolling:
-        valid &= slot[None, :] >= start_pos[:, None]
+    valid = jnp.arange(T)[None, :] <= positions[:, None]
     s = jnp.where(valid[:, None, None, None, :], s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v_cache.dtype), v_cache)

@@ -40,6 +40,7 @@ from .ssm import init_ssm_cache, ssm_apply, ssm_decode, ssm_init
 __all__ = [
     "init_params", "params_pspecs", "loss_fn", "prefill", "decode_step",
     "init_cache", "cache_pspecs", "batch_pspecs", "segment_counts",
+    "reset_cache_slot",
 ]
 
 Params = Dict[str, Any]
@@ -309,9 +310,12 @@ def _head_mask(cfg: ModelConfig):
 
 
 def _attn_core(p, x, cfg: ModelConfig, *, positions, mode: str,
-               kv_cache=None, cache_len=None, rolling=False,
-               ext_kv=None, start_pos=None):
-    """Shared attention path. Returns (delta, new_kv_cache or None)."""
+               kv_cache=None, rolling=False, ext_kv=None):
+    """Shared attention path. Returns (delta, new_kv_cache or None).
+
+    In decode mode ``positions`` is (B, 1): each row's new token is written
+    to its own cache index (``pos % T`` for a rolling cache) and attends to
+    the indices at or below it."""
     B = x.shape[0]
     hp, dh = cfg.n_heads_padded, cfg.hdim
     h = rms_norm(x, p["norm1"], cfg.norm_eps)
@@ -327,16 +331,15 @@ def _attn_core(p, x, cfg: ModelConfig, *, positions, mode: str,
         k = apply_rope(k, positions, cfg.rope_theta)
     if mode == "decode" and ext_kv is None:
         k_cache, v_cache = kv_cache
-        T = k_cache.shape[1]
-        slot = (cache_len % T) if rolling else cache_len
-        k_cache = lax.dynamic_update_slice_in_dim(k_cache, k.astype(k_cache.dtype), slot, axis=1)
-        v_cache = lax.dynamic_update_slice_in_dim(v_cache, v.astype(v_cache.dtype), slot, axis=1)
+        pos = positions[:, 0]
+        idx = pos % k_cache.shape[1] if rolling else pos
+        rows = jnp.arange(B)
+        k_cache = k_cache.at[rows, idx].set(k[:, 0].astype(k_cache.dtype))
+        v_cache = v_cache.at[rows, idx].set(v[:, 0].astype(v_cache.dtype))
         new_cache = (k_cache, v_cache)
-        attn = decode_attention(q, k_cache, v_cache, cache_len + 1,
-                                window=cfg.sliding_window, rolling=rolling,
-                                start_pos=start_pos)
+        attn = decode_attention(q, k_cache, v_cache, pos)
     elif mode == "decode":  # cross-attn decode: attend the cached vision kv
-        attn = decode_attention(q, k, v, jnp.int32(k.shape[1]))
+        attn = decode_attention(q, k, v, jnp.full((B,), k.shape[1] - 1))
         new_cache = None
     else:
         causal = ext_kv is None
@@ -391,12 +394,11 @@ def _ffn_part(p, x, cfg: ModelConfig):
     return x, jnp.float32(0)
 
 
-def _attn_block(p, x, cfg, *, positions, mode, kv_cache=None, cache_len=None,
-                rolling=False, ext_kv=None, start_pos=None):
+def _attn_block(p, x, cfg, *, positions, mode, kv_cache=None, rolling=False,
+                ext_kv=None):
     delta, new_cache = _attn_core(p, x, cfg, positions=positions, mode=mode,
-                                  kv_cache=kv_cache, cache_len=cache_len,
-                                  rolling=rolling, ext_kv=ext_kv,
-                                  start_pos=start_pos)
+                                  kv_cache=kv_cache, rolling=rolling,
+                                  ext_kv=ext_kv)
     x = x + delta
     x, aux = _ffn_part(p, x, cfg)
     return x, aux, new_cache
@@ -417,8 +419,9 @@ def _vision_kv(params, vision_embeds, cfg: ModelConfig):
 
 def forward_hidden(params: Params, x: jnp.ndarray, cfg: ModelConfig, *,
                    mode: str = "train", positions=None, cache=None,
-                   cache_len=None, vision_stream=None, start_pos=None):
-    """Run all blocks. x: (B,S,d) embeddings. Returns (x, aux, new_cache)."""
+                   vision_stream=None):
+    """Run all blocks. x: (B,S,d) embeddings; positions (B,S), in decode
+    mode each row's own cache position. Returns (x, aux, new_cache)."""
     aux_total = jnp.float32(0)
     new_cache: Dict[str, Any] = {}
     rolling = cfg.sliding_window is not None and mode == "decode"
@@ -429,8 +432,7 @@ def forward_hidden(params: Params, x: jnp.ndarray, cfg: ModelConfig, *,
             p = xs["p"]
             kvc = (xs["k"], xs["v"]) if mode == "decode" else None
             x, a, nc = _attn_block(p, x, cfg, positions=positions, mode=mode,
-                                   kv_cache=kvc, cache_len=cache_len,
-                                   rolling=rolling, start_pos=start_pos)
+                                   kv_cache=kvc, rolling=rolling)
             ys = {}
             if nc is not None:
                 ys = {"k": nc[0], "v": nc[1]}
@@ -453,40 +455,38 @@ def forward_hidden(params: Params, x: jnp.ndarray, cfg: ModelConfig, *,
         shared_p = params["shared_attn"]
 
         def group(carry, xs):
-            x, aux, clen = carry
+            x, aux = carry
             # inner ssm stack
             x, _, ssm_c = _ssm_segment_inner(xs["ssm"], x, cfg, mode,
                                              {"h": xs.get("h"), "conv": xs.get("conv")})
             # shared attention block
             kvc = (xs["k"], xs["v"]) if mode == "decode" else None
             x, a, nc = _attn_block(shared_p, x, cfg, positions=positions,
-                                   mode=mode, kv_cache=kvc, cache_len=clen,
-                                   start_pos=start_pos)
+                                   mode=mode, kv_cache=kvc, rolling=rolling)
             ys = dict(ssm_c)
             if nc is not None:
                 ys["k"], ys["v"] = nc
-            return (x, aux + a, clen), ys
+            return (x, aux + a), ys
 
         xs = {"ssm": params["ssm"]}
         if mode == "decode":
             xs.update({"h": cache["h"], "conv": cache["conv"],
                        "k": cache["k"], "v": cache["v"]})
-        (x, aux_total, _), ys = lax.scan(_maybe_remat(group, cfg),
-                                         (x, aux_total, cache_len if cache_len is not None else jnp.int32(0)), xs,
-                                         unroll=scan_unroll())
+        (x, aux_total), ys = lax.scan(_maybe_remat(group, cfg),
+                                      (x, aux_total), xs, unroll=scan_unroll())
         if mode in ("decode", "prefill") and ys:
             new_cache = ys
 
     elif cfg.family == "vlm":
         def group(carry, xs):
-            x, aux, clen = carry
+            x, aux = carry
 
             def inner(c2, p_inner):
                 x2, aux2 = c2
                 kvc = (p_inner["k"], p_inner["v"]) if mode == "decode" else None
                 x2, a2, nc2 = _attn_block(p_inner["p"], x2, cfg, positions=positions,
-                                          mode=mode, kv_cache=kvc, cache_len=clen,
-                                          start_pos=start_pos)
+                                          mode=mode, kv_cache=kvc,
+                                          rolling=rolling)
                 ys2 = {"k": nc2[0], "v": nc2[1]} if nc2 is not None else {}
                 return (x2, aux2 + a2), ys2
 
@@ -501,14 +501,13 @@ def forward_hidden(params: Params, x: jnp.ndarray, cfg: ModelConfig, *,
             vc = jnp.einsum("bpd,dhk->bphk", vision_stream, pc["wv"])
             x, a, _ = _attn_block(pc, x, cfg, positions=positions, mode=mode,
                                   ext_kv=(kc, vc))
-            return (x, aux + a, clen), ys_inner
+            return (x, aux + a), ys_inner
 
         xs = {"self": params["self"], "cross": params["cross"]}
         if mode == "decode":
             xs["k"], xs["v"] = cache["k"], cache["v"]
-        (x, aux_total, _), ys = lax.scan(_maybe_remat(group, cfg),
-                                         (x, aux_total, cache_len if cache_len is not None else jnp.int32(0)), xs,
-                                         unroll=scan_unroll())
+        (x, aux_total), ys = lax.scan(_maybe_remat(group, cfg),
+                                      (x, aux_total), xs, unroll=scan_unroll())
         if mode in ("decode", "prefill") and ys:
             new_cache = ys
     else:
@@ -595,19 +594,18 @@ def prefill(params: Params, batch, cfg: ModelConfig):
     return logits, new_cache
 
 
-def decode_step(params: Params, batch, cache, cache_len, cfg: ModelConfig):
+def decode_step(params: Params, batch, cache, positions, cfg: ModelConfig):
     """One token for every sequence in the batch.
 
     batch: {"tokens": (B,1)} (or {"frames": (B,1,d)} for audio);
-    cache_len: scalar int32 — valid length before this step.
+    positions: (B,) int32 — each row's cache position, the number of tokens
+    that row has already written.
     Returns (logits, new_cache)."""
     x, vision = _embed_batch(params, batch, cfg)
-    positions = jnp.broadcast_to(cache_len, (x.shape[0], 1))
-    start_pos = batch.get("start_pos")
+    pos = jnp.asarray(positions, jnp.int32)
     x, _, new_cache = forward_hidden(params, x, cfg, mode="decode",
-                                     positions=positions, cache=cache,
-                                     cache_len=cache_len, vision_stream=vision,
-                                     start_pos=start_pos)
+                                     positions=pos[:, None], cache=cache,
+                                     vision_stream=vision)
     last = x[:, -1]
     if cfg.n_codebooks:
         logits = jnp.stack([_logits_full(last, params["lm_head"][cb], cfg)
@@ -665,6 +663,22 @@ def cache_pspecs(cfg: ModelConfig, batch: int, dp_divisible: bool) -> Dict[str, 
         s = (None, None, b, "mp", None, None)
         return {"k": s, "v": s}
     raise ValueError(cfg.family)
+
+
+def reset_cache_slot(cache, slot, cfg: ModelConfig):
+    """Zero batch row ``slot`` of the recurrent state (SSM ``h`` and
+    ``conv``), each leaf at its own batch axis as ``cache_pspecs`` places
+    it.  K/V are left as they are: a row attends only to the indices at or
+    below its position, which it has written itself."""
+    specs = cache_pspecs(cfg, 1, dp_divisible=True)
+
+    def zero(leaf, spec):
+        ax = spec.index("dp")
+        row = jnp.zeros(leaf.shape[:ax] + (1,) + leaf.shape[ax + 1:], leaf.dtype)
+        return lax.dynamic_update_slice_in_dim(leaf, row, slot, axis=ax)
+
+    return {name: leaf if name in ("k", "v") else zero(leaf, specs[name])
+            for name, leaf in cache.items()}
 
 
 def batch_pspecs(cfg: ModelConfig, batch: int, dp_size: int) -> Dict[str, Any]:

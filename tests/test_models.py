@@ -63,7 +63,7 @@ def test_arch_decode_runs(arch):
     if cfg.family == "vlm":
         dbatch["vision_embeds"] = jax.random.normal(KEY, (B, cfg.vision_patches, cfg.vision_dim))
     logits, cache2 = jax.jit(lambda p, b, c, l: decode_step(p, b, c, l, cfg))(
-        params, dbatch, cache, jnp.int32(0))
+        params, dbatch, cache, jnp.zeros((B,), jnp.int32))
     assert np.all(np.isfinite(np.asarray(logits, np.float32)))
     # cache must actually change
     diff = sum(float(jnp.sum(jnp.abs(a.astype(jnp.float32) - b.astype(jnp.float32))))
@@ -73,21 +73,29 @@ def test_arch_decode_runs(arch):
 
 def test_decode_matches_full_forward():
     """Greedy decode over a prompt step-by-step == teacher-forced forward.
-    (dense arch; the strongest end-to-end consistency check we have)"""
+    (dense arch; the strongest end-to-end consistency check we have)  Row 1
+    starts three steps after row 0, so the rows sit at different cache
+    positions throughout, as slots admitted at different steps do."""
     cfg = ARCHS["phi3-mini-3.8b"].smoke()
     params = init_params(cfg, KEY)
-    B, S = 1, 12
+    B, S, lag = 2, 12, 3
     toks = jax.random.randint(jax.random.PRNGKey(5), (B, S), 0, cfg.vocab_size)
     # full forward logits at final position, via prefill
     logits_full, _ = prefill(params, {"tokens": toks}, cfg)
-    # token-by-token decode
+    # token-by-token decode, row 1 behind row 0 (until it starts it writes
+    # position 0 again and again, which its first real step overwrites)
     cache = init_cache(cfg, B, S + 2)
     step = jax.jit(lambda p, b, c, l: decode_step(p, b, c, l, cfg))
-    for t in range(S):
-        logits_step, cache = step(params, {"tokens": toks[:, t:t + 1]},
-                                  cache, jnp.int32(t))
-    np.testing.assert_allclose(np.asarray(logits_step), np.asarray(logits_full),
-                               atol=2e-2, rtol=2e-2)
+    last = [None, None]
+    for t in range(S + lag):
+        pos = np.array([min(t, S - 1), max(t - lag, 0)], np.int32)
+        fed = toks[jnp.arange(B), pos][:, None]
+        logits_step, cache = step(params, {"tokens": fed}, cache, pos)
+        for b in range(B):
+            if t == S - 1 + b * lag:
+                last[b] = logits_step[b]
+    np.testing.assert_allclose(np.asarray(jnp.stack(last)),
+                               np.asarray(logits_full), atol=2e-2, rtol=2e-2)
 
 
 def test_head_padding_exactness():

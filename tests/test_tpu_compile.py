@@ -1,4 +1,5 @@
-"""Compile-only checks of the Pallas kernels for a TPU v5e, at real widths.
+"""Compile-only checks for a TPU v5e, at real widths: the Pallas kernels,
+and the serving engine's decode program for Phi-3-mini.
 
 The TPU compiler is installed even where no chip is attached: it compiles
 for a described v5e topology and refuses what the chip's compiler would
@@ -94,3 +95,36 @@ def test_ssd_scan_compiles_for_v5e_at_mamba2_130m_width(shape):
         shape((H,), jnp.float32), shape((b, T, N), jnp.bfloat16),
         shape((b, T, N), jnp.bfloat16), chunk=256, interpret=False).compile()
     _assert_mosaic(compiled)
+
+
+def test_phi3_mini_serving_step_compiles_for_v5e_and_fits_one_chip(shape):
+    """``ServeEngine``'s decode program at Phi-3-mini's published widths,
+    as the ``phi3-chat-backlog`` cell runs it: 4 slots of 2048 positions,
+    a position per slot.  The cache is donated and updated in place, and
+    weights, cache and the program's temporaries fit one 16 GB chip.  The
+    ``serve.*`` device readers take every op of the traced window, so no
+    op name needs pinning."""
+    import functools
+
+    from repro.configs import ARCHS
+    from repro.launch.serve import serve_step
+    from repro.models import init_cache, init_params
+
+    cfg, B, T = ARCHS["phi3-mini-3.8b"], 4, 2048
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: shape(a.shape, a.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(lambda: init_cache(cfg, B, T)))
+    batch = {"tokens": shape((B, 1), jnp.int32), "last": shape((B,), jnp.int32)}
+    compiled = jax.jit(functools.partial(serve_step, cfg=cfg),
+                       donate_argnums=(2,)).lower(
+        params, batch, cache, shape((B,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes == cache_bytes
+    held = mem.argument_size_in_bytes + mem.output_size_in_bytes - \
+        mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert held < 16e9
