@@ -13,8 +13,10 @@ With ``causal_skip=True`` the chunked path only visits the lower-triangular
 (query-chunk, kv-chunk) pairs — S(S+ck)/2 instead of S² score FLOPs — by
 enumerating the valid pairs statically (beyond-paper optimisation, §Perf).
 
-Decode (single new token against a KV cache) is a separate, simpler path.
-All shapes: q (B, S, H, Dh); k/v (B, T, Hkv, Dh) with H % Hkv == 0.
+Decode (single new token against a KV cache) is a separate, simpler path;
+``cached_decode_attention`` reads the decode cache, whose K/V keep heads
+and head dimension merged, (B, T, Hkv·Dh).
+All other shapes: q (B, S, H, Dh); k/v (B, T, Hkv, Dh) with H % Hkv == 0.
 """
 from __future__ import annotations
 
@@ -27,7 +29,7 @@ from jax import lax
 
 from .layers import scan_unroll
 
-__all__ = ["attention", "decode_attention"]
+__all__ = ["attention", "decode_attention", "cached_decode_attention"]
 
 _NEG = -1e30
 
@@ -178,3 +180,34 @@ def decode_attention(q, k_cache, v_cache, positions) -> jnp.ndarray:
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v_cache.dtype), v_cache)
     return out.reshape(B, 1, H, Dh)
+
+
+def cached_decode_attention(q, k_cache, v_cache, positions) -> jnp.ndarray:
+    """``decode_attention`` over a self-attention cache that keeps heads
+    and head dimension merged in its minor axis: caches (B, T, Hkv·Dh).
+
+    Splitting that axis back into (Hkv, Dh) changes the array's layout
+    on a TPU, where a head dimension such as 96 does not fill a 128-lane
+    tile: a copy of every layer a step.  So the heads stay in the minor
+    axis.  The query is laid out block-diagonally, (B, Hkv·Dh, H): query
+    head h·g + j holds its Dh values in the rows of KV head h and zeros
+    elsewhere.  Scores are one contraction over the merged axis;
+    probabilities times V give every (query head, KV head) block, of
+    which the diagonal ones are kept.  The zero blocks cost Hkv times the
+    MXU work of the attention products, which a step bound by HBM
+    bandwidth hides.  The mask is ``decode_attention``'s."""
+    B, _, H, Dh = q.shape
+    T, C = k_cache.shape[1], k_cache.shape[2]
+    Hkv = C // Dh
+    g = H // Hkv
+    eye = jnp.eye(Hkv, dtype=q.dtype)
+    qt = q.reshape(B, Hkv, g, Dh).transpose(0, 3, 1, 2)          # (B,Dh,h,g)
+    qbd = (qt[:, None] * eye[None, :, None, :, None]).reshape(B, C, H)
+    s = jnp.einsum("btc,bch->bht", k_cache, qbd,
+                   preferred_element_type=jnp.float32) * (Dh ** -0.5)
+    valid = jnp.arange(T)[None, :] <= positions[:, None]
+    s = jnp.where(valid[:, None, :], s, _NEG)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bht,btc->bhc", p.astype(v_cache.dtype), v_cache)
+    o = jnp.diagonal(o.reshape(B, Hkv, g, Hkv, Dh), axis1=1, axis2=3)
+    return o.transpose(0, 3, 1, 2).reshape(B, 1, H, Dh)     # from (B,g,Dh,h)

@@ -22,7 +22,7 @@ mesh (padded heads are hard-masked so numerics equal the unpadded model).
 from __future__ import annotations
 
 from functools import partial
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +30,7 @@ from jax import lax
 
 from ..parallel.context import (current_ctx, fsdp_gather, manual_model,
                                 psum_compat, shard)
-from .attention import attention, decode_attention
+from .attention import attention, cached_decode_attention, decode_attention
 from .config import ModelConfig
 from .layers import (dense_init, embed_init, rms_norm, apply_rope,
                      scan_unroll, swiglu)
@@ -309,9 +309,31 @@ def _head_mask(cfg: ModelConfig):
     return (jnp.arange(hp) < cfg.n_heads).astype(cfg.param_dtype)
 
 
+class LayerKV(NamedTuple):
+    """The decode cache's K and V stacks, ``(*lead, B, T, kv·dh)``, and
+    the indices ``at`` of one layer in their leading axes."""
+    k: jnp.ndarray
+    v: jnp.ndarray
+    at: Tuple = ()
+
+
+def _write_kv(kv: LayerKV, k, v, pos, rolling: bool) -> LayerKV:
+    """Write each row's new K/V, (B, 1, kv, dh), into layer ``kv.at`` of
+    the stacks at the row's own index (``pos % T`` for a rolling cache):
+    one scatter a stack, in place, with heads and head dimension merged
+    as the cache keeps them."""
+    B = k.shape[0]
+    idx = pos % kv.k.shape[-2] if rolling else pos
+    at = kv.at + (jnp.arange(B), idx)
+    return kv._replace(k=kv.k.at[at].set(k.reshape(B, -1).astype(kv.k.dtype)),
+                       v=kv.v.at[at].set(v.reshape(B, -1).astype(kv.v.dtype)))
+
+
 def _attn_core(p, x, cfg: ModelConfig, *, positions, mode: str,
                kv_cache=None, rolling=False, ext_kv=None):
-    """Shared attention path. Returns (delta, new_kv_cache or None).
+    """Shared attention path. Returns (delta, new cache or None): in
+    decode mode the ``LayerKV`` with this layer's rows written, in
+    prefill mode this layer's (k, v), each (B, S, kv·dh).
 
     In decode mode ``positions`` is (B, 1): each row's new token is written
     to its own cache index (``pos % T`` for a rolling cache) and attends to
@@ -330,14 +352,10 @@ def _attn_core(p, x, cfg: ModelConfig, *, positions, mode: str,
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
     if mode == "decode" and ext_kv is None:
-        k_cache, v_cache = kv_cache
         pos = positions[:, 0]
-        idx = pos % k_cache.shape[1] if rolling else pos
-        rows = jnp.arange(B)
-        k_cache = k_cache.at[rows, idx].set(k[:, 0].astype(k_cache.dtype))
-        v_cache = v_cache.at[rows, idx].set(v[:, 0].astype(v_cache.dtype))
-        new_cache = (k_cache, v_cache)
-        attn = decode_attention(q, k_cache, v_cache, pos)
+        new_cache = _write_kv(kv_cache, k, v, pos, rolling)
+        attn = cached_decode_attention(q, new_cache.k[new_cache.at],
+                                       new_cache.v[new_cache.at], pos)
     elif mode == "decode":  # cross-attn decode: attend the cached vision kv
         attn = decode_attention(q, k, v, jnp.full((B,), k.shape[1] - 1))
         new_cache = None
@@ -347,7 +365,7 @@ def _attn_core(p, x, cfg: ModelConfig, *, positions, mode: str,
                          impl=cfg.attn_impl, q_chunk=cfg.attn_q_chunk,
                          kv_chunk=cfg.attn_kv_chunk, causal_skip=cfg.causal_skip)
         if ext_kv is None and mode == "prefill":
-            new_cache = (k, v)
+            new_cache = (k.reshape(B, k.shape[1], -1), v.reshape(B, v.shape[1], -1))
         else:
             new_cache = None
     mask = _head_mask(cfg)
@@ -407,14 +425,40 @@ def _attn_block(p, x, cfg, *, positions, mode, kv_cache=None, rolling=False,
 # ==========================================================================
 # forward
 # ==========================================================================
-def _maybe_remat(fn, cfg: ModelConfig):
-    return jax.checkpoint(fn) if cfg.remat else fn
-
-
 def _vision_kv(params, vision_embeds, cfg: ModelConfig):
     """Project the (stub) vision embeddings once; per-cross-layer K/V are
     computed from this shared stream inside each cross block."""
     return (vision_embeds @ params["vision_proj"]).astype(params["vision_proj"].dtype)
+
+
+def _layer_scan(body, carry, xs, kv: Optional[LayerKV], remat: bool):
+    """``lax.scan`` of ``body(carry, xs, kv) -> (carry, ys, kv)`` over the
+    leading axis of ``xs``; returns (carry, ys, kv).
+
+    In decode mode ``kv`` holds the cache's K/V stacks.  They ride in the
+    scan's carry, never in ``xs``/``ys``, beside the layer index, which
+    ``body`` finds appended to ``kv.at``: each layer writes its rows into
+    the stacks in place and attention reads its layer where it lies, so
+    no step slices, restacks or copies a whole layer or stack.  A nested
+    scan (vlm) passes on the ``kv`` its own body was given.  Elsewhere
+    ``kv`` is None and ``body`` gets None."""
+    if kv is None:
+        def step(c, x):
+            c, ys, _ = body(c, x, None)
+            return c, ys
+        carry, ys = lax.scan(jax.checkpoint(step) if remat else step, carry,
+                             xs, unroll=scan_unroll())
+        return carry, ys, None
+
+    def step(c, x):
+        c, k, v, i = c
+        c, ys, out = body(c, x, LayerKV(k, v, kv.at + (i,)))
+        return (c, out.k, out.v, i + 1), ys
+
+    (carry, k, v, _), ys = lax.scan(
+        jax.checkpoint(step) if remat else step,
+        (carry, kv.k, kv.v, jnp.int32(0)), xs, unroll=scan_unroll())
+    return carry, ys, kv._replace(k=k, v=v)
 
 
 def forward_hidden(params: Params, x: jnp.ndarray, cfg: ModelConfig, *,
@@ -425,94 +469,65 @@ def forward_hidden(params: Params, x: jnp.ndarray, cfg: ModelConfig, *,
     aux_total = jnp.float32(0)
     new_cache: Dict[str, Any] = {}
     rolling = cfg.sliding_window is not None and mode == "decode"
+    kv = LayerKV(cache["k"], cache["v"]) if mode == "decode" and \
+        "k" in cache else None
+
+    def attn(carry, p, kv):
+        """One self-attention block as a ``_layer_scan`` body: its ys
+        hold the layer's prefill K/V."""
+        x, aux = carry
+        x, a, nc = _attn_block(p, x, cfg, positions=positions, mode=mode,
+                               kv_cache=kv, rolling=rolling)
+        if mode == "prefill":
+            return (x, aux + a), {"k": nc[0], "v": nc[1]}, None
+        return (x, aux + a), {}, nc
 
     if cfg.family in ("dense", "moe", "audio"):
-        def body(carry, xs):
-            x, aux = carry
-            p = xs["p"]
-            kvc = (xs["k"], xs["v"]) if mode == "decode" else None
-            x, a, nc = _attn_block(p, x, cfg, positions=positions, mode=mode,
-                                   kv_cache=kvc, rolling=rolling)
-            ys = {}
-            if nc is not None:
-                ys = {"k": nc[0], "v": nc[1]}
-            return (x, aux + a), ys
-
-        xs = {"p": params["blocks"]}
-        if mode == "decode":
-            xs["k"], xs["v"] = cache["k"], cache["v"]
-        (x, aux_total), ys = lax.scan(_maybe_remat(body, cfg), (x, aux_total), xs,
-                                      unroll=scan_unroll())
-        if mode in ("decode", "prefill") and ys:
-            new_cache = ys
+        (x, aux_total), new_cache, kv = _layer_scan(
+            attn, (x, aux_total), params["blocks"], kv, cfg.remat)
 
     elif cfg.family == "ssm":
         x, aux_total, new_cache = _ssm_segment(params["blocks"], x, cfg, mode,
                                                cache, aux_total)
 
     elif cfg.family == "hybrid":
-        segs = segment_counts(cfg)
-        shared_p = params["shared_attn"]
-
-        def group(carry, xs):
+        def group(carry, xs, kv):
             x, aux = carry
             # inner ssm stack
             x, _, ssm_c = _ssm_segment_inner(xs["ssm"], x, cfg, mode,
                                              {"h": xs.get("h"), "conv": xs.get("conv")})
             # shared attention block
-            kvc = (xs["k"], xs["v"]) if mode == "decode" else None
-            x, a, nc = _attn_block(shared_p, x, cfg, positions=positions,
-                                   mode=mode, kv_cache=kvc, rolling=rolling)
-            ys = dict(ssm_c)
-            if nc is not None:
-                ys["k"], ys["v"] = nc
-            return (x, aux + a), ys
+            carry, ys, kv = attn((x, aux), params["shared_attn"], kv)
+            return carry, {**ssm_c, **ys}, kv
 
         xs = {"ssm": params["ssm"]}
         if mode == "decode":
-            xs.update({"h": cache["h"], "conv": cache["conv"],
-                       "k": cache["k"], "v": cache["v"]})
-        (x, aux_total), ys = lax.scan(_maybe_remat(group, cfg),
-                                      (x, aux_total), xs, unroll=scan_unroll())
-        if mode in ("decode", "prefill") and ys:
-            new_cache = ys
+            xs.update({"h": cache["h"], "conv": cache["conv"]})
+        (x, aux_total), new_cache, kv = _layer_scan(
+            group, (x, aux_total), xs, kv, cfg.remat)
 
     elif cfg.family == "vlm":
-        def group(carry, xs):
+        def group(carry, xs, kv):
+            # self-attention layers: a scan of their own, whose layer
+            # index follows the group's in ``kv.at``
+            carry, ys, kv = _layer_scan(attn, carry, xs["self"], kv, False)
             x, aux = carry
-
-            def inner(c2, p_inner):
-                x2, aux2 = c2
-                kvc = (p_inner["k"], p_inner["v"]) if mode == "decode" else None
-                x2, a2, nc2 = _attn_block(p_inner["p"], x2, cfg, positions=positions,
-                                          mode=mode, kv_cache=kvc,
-                                          rolling=rolling)
-                ys2 = {"k": nc2[0], "v": nc2[1]} if nc2 is not None else {}
-                return (x2, aux2 + a2), ys2
-
-            xs_in = {"p": xs["self"]}
-            if mode == "decode":
-                xs_in["k"], xs_in["v"] = xs["k"], xs["v"]
-            (x, aux), ys_inner = lax.scan(inner, (x, aux), xs_in,
-                                          unroll=scan_unroll())
             # cross-attn over the vision stream
             pc = xs["cross"]
             kc = jnp.einsum("bpd,dhk->bphk", vision_stream, pc["wk"])
             vc = jnp.einsum("bpd,dhk->bphk", vision_stream, pc["wv"])
             x, a, _ = _attn_block(pc, x, cfg, positions=positions, mode=mode,
                                   ext_kv=(kc, vc))
-            return (x, aux + a), ys_inner
+            return (x, aux + a), ys, kv
 
         xs = {"self": params["self"], "cross": params["cross"]}
-        if mode == "decode":
-            xs["k"], xs["v"] = cache["k"], cache["v"]
-        (x, aux_total), ys = lax.scan(_maybe_remat(group, cfg),
-                                      (x, aux_total), xs, unroll=scan_unroll())
-        if mode in ("decode", "prefill") and ys:
-            new_cache = ys
+        (x, aux_total), new_cache, kv = _layer_scan(
+            group, (x, aux_total), xs, kv, cfg.remat)
     else:
         raise ValueError(cfg.family)
 
+    if kv is not None:
+        new_cache.update(k=kv.k, v=kv.v)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return x, aux_total, new_cache
 
@@ -583,8 +598,8 @@ def prefill(params: Params, batch, cfg: ModelConfig):
                                      positions=positions, vision_stream=vision)
     if cfg.sliding_window is not None and "k" in new_cache:
         w = min(cfg.sliding_window, S)
-        new_cache["k"] = new_cache["k"][:, :, -w:]
-        new_cache["v"] = new_cache["v"][:, :, -w:]
+        new_cache["k"] = new_cache["k"][..., -w:, :]
+        new_cache["v"] = new_cache["v"][..., -w:, :]
     last = x[:, -1]
     if cfg.n_codebooks:
         logits = jnp.stack([_logits_full(last, params["lm_head"][cb], cfg)
@@ -619,38 +634,40 @@ def decode_step(params: Params, batch, cache, positions, cfg: ModelConfig):
 # caches & input specs
 # ==========================================================================
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, Any]:
-    """Allocate an (empty) decode cache matching forward_hidden's layout."""
-    kv = _kv_heads_alloc(cfg)
-    dh = cfg.hdim
+    """Allocate an (empty) decode cache matching forward_hidden's layout.
+
+    Each K and V stack keeps a layer's heads and head dimension merged in
+    its minor axis, ``(layers, batch, T, kv·dh)`` (vlm: ``(groups, inner,
+    batch, T, kv·dh)``): on a TPU that axis fills whole 128-lane tiles
+    where a head dimension such as 96 would not, so the decode step's
+    one-row writes and its attention read the cache in the layout it is
+    stored in."""
+    kvd = _kv_heads_alloc(cfg) * cfg.hdim
     T = min(max_len, cfg.sliding_window) if cfg.sliding_window else max_len
     dt = cfg.param_dtype
     segs = segment_counts(cfg)
 
-    def kv_pair(n):
-        return (jnp.zeros((n, batch, T, kv, dh), dt),
-                jnp.zeros((n, batch, T, kv, dh), dt))
+    def kv_pair(*lead):
+        k = jnp.zeros((*lead, batch, T, kvd), dt)
+        return {"k": k, "v": jnp.zeros_like(k)}
 
     if cfg.family in ("dense", "moe", "audio"):
-        k, v = kv_pair(segs["blocks"])
-        return {"k": k, "v": v}
+        return kv_pair(segs["blocks"])
     if cfg.family == "ssm":
         c = jax.vmap(lambda _: init_ssm_cache(batch, cfg, dt))(jnp.arange(segs["blocks"]))
         return c
     if cfg.family == "hybrid":
         g, inner = segs["groups"], segs["ssm_per_group"]
         ssm_c = jax.vmap(lambda _: jax.vmap(lambda __: init_ssm_cache(batch, cfg, dt))(jnp.arange(inner)))(jnp.arange(g))
-        k, v = kv_pair(g)
-        return {"h": ssm_c["h"], "conv": ssm_c["conv"], "k": k, "v": v}
+        return {"h": ssm_c["h"], "conv": ssm_c["conv"], **kv_pair(g)}
     if cfg.family == "vlm":
-        g, inner = segs["groups"], segs["self_per_group"]
-        k = jnp.zeros((g, inner, batch, T, kv, dh), dt)
-        return {"k": k, "v": jnp.zeros_like(k)}
+        return kv_pair(segs["groups"], segs["self_per_group"])
     raise ValueError(cfg.family)
 
 
 def cache_pspecs(cfg: ModelConfig, batch: int, dp_divisible: bool) -> Dict[str, Any]:
     b = "dp" if dp_divisible else None
-    kv_spec = (None, b, "mp", None, None)
+    kv_spec = (None, b, "mp", None)
     if cfg.family in ("dense", "moe", "audio"):
         return {"k": kv_spec, "v": kv_spec}
     if cfg.family == "ssm":
@@ -660,7 +677,7 @@ def cache_pspecs(cfg: ModelConfig, batch: int, dp_divisible: bool) -> Dict[str, 
                 "conv": (None, None, b, None, None),
                 "k": kv_spec, "v": kv_spec}
     if cfg.family == "vlm":
-        s = (None, None, b, "mp", None, None)
+        s = (None, None, b, "mp", None)
         return {"k": s, "v": s}
     raise ValueError(cfg.family)
 

@@ -9,6 +9,7 @@ import pytest
 from repro.configs import ARCHS
 from repro.models import (decode_step, init_cache, init_params, loss_fn,
                           prefill)
+from repro.models.model import reset_cache_slot
 from repro.models.attention import attention, naive_attention
 from repro.models.config import ModelConfig
 from repro.models.moe import moe_apply, moe_init
@@ -71,26 +72,45 @@ def test_arch_decode_runs(arch):
     assert diff > 0
 
 
-def test_decode_matches_full_forward():
-    """Greedy decode over a prompt step-by-step == teacher-forced forward.
-    (dense arch; the strongest end-to-end consistency check we have)  Row 1
-    starts three steps after row 0, so the rows sit at different cache
-    positions throughout, as slots admitted at different steps do."""
-    cfg = ARCHS["phi3-mini-3.8b"].smoke()
+# every family whose decode step carries a K/V cache through the layer scan
+DECODE_CASES = {
+    "phi3-mini-3.8b": ARCHS["phi3-mini-3.8b"].smoke(),              # MHA
+    "deepseek-coder-33b": ARCHS["deepseek-coder-33b"].smoke().replace(
+        n_kv_heads=2),                                               # GQA, 2 groups
+    "zamba2-2.7b": ARCHS["zamba2-2.7b"].smoke(),                     # hybrid
+    "musicgen-medium": ARCHS["musicgen-medium"].smoke(),             # audio
+    "llama-3.2-vision-90b": ARCHS["llama-3.2-vision-90b"].smoke(),   # vlm
+    "mixtral-8x7b": ARCHS["mixtral-8x7b"].smoke(),                   # moe, window
+}
+
+
+@pytest.mark.parametrize("arch", sorted(DECODE_CASES))
+def test_decode_matches_full_forward(arch):
+    """Greedy decode over a prompt step-by-step == teacher-forced forward
+    (the strongest end-to-end consistency check we have).  Row 1 starts
+    three steps after row 0, so the rows sit at different cache positions
+    throughout, as slots admitted at different steps do; its recurrent
+    state, if any, is reset as it starts, as the engine does on admission."""
+    cfg = DECODE_CASES[arch]
     params = init_params(cfg, KEY)
-    B, S, lag = 2, 12, 3
-    toks = jax.random.randint(jax.random.PRNGKey(5), (B, S), 0, cfg.vocab_size)
+    B, S, lag = 2, 16, 3            # S: a whole number of SSD chunks
+    batch = make_batch(cfg, B, S, jax.random.PRNGKey(5))
+    batch.pop("labels")
+    seq = "frames" if cfg.family == "audio" else "tokens"
     # full forward logits at final position, via prefill
-    logits_full, _ = prefill(params, {"tokens": toks}, cfg)
+    logits_full, _ = prefill(params, batch, cfg)
     # token-by-token decode, row 1 behind row 0 (until it starts it writes
     # position 0 again and again, which its first real step overwrites)
     cache = init_cache(cfg, B, S + 2)
     step = jax.jit(lambda p, b, c, l: decode_step(p, b, c, l, cfg))
     last = [None, None]
     for t in range(S + lag):
+        if t == lag:
+            cache = reset_cache_slot(cache, 1, cfg)
         pos = np.array([min(t, S - 1), max(t - lag, 0)], np.int32)
-        fed = toks[jnp.arange(B), pos][:, None]
-        logits_step, cache = step(params, {"tokens": fed}, cache, pos)
+        fed = dict(batch)
+        fed[seq] = batch[seq][jnp.arange(B), pos][:, None]
+        logits_step, cache = step(params, fed, cache, pos)
         for b in range(B):
             if t == S - 1 + b * lag:
                 last[b] = logits_step[b]

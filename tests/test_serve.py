@@ -75,6 +75,11 @@ CASES = {
     "rolling_window": (dense(sliding_window=8), dict(max_batch=2, max_len=64),
                        [requests(6, 3, (4, 20), (4, 16)),
                         requests(7, 3, (4, 20), (4, 16))]),
+    # grouped-query attention: two query heads a KV head, staggered and
+    # recycled as in "staggered"
+    "gqa": (dense(n_kv_heads=2), dict(max_batch=3, max_len=64),
+            [requests(11, 4, (1, 12), (1, 10)),
+             requests(12, 3, (1, 12), (1, 10))]),
     # eight requests of 19 positions through two slots: 76 engine steps,
     # three times a slot's 24 positions
     "past_max_len": (dense(), dict(max_batch=2, max_len=24),

@@ -9,6 +9,7 @@ results or speed.  The topology is described inside a module fixture,
 never at import time: only one process at a time may load the TPU
 library, and every test worker imports this file.
 """
+import math
 import os
 import re
 
@@ -97,13 +98,12 @@ def test_ssd_scan_compiles_for_v5e_at_mamba2_130m_width(shape):
     _assert_mosaic(compiled)
 
 
-def test_phi3_mini_serving_step_compiles_for_v5e_and_fits_one_chip(shape):
+@pytest.fixture(scope="module")
+def phi3_serve_step(shape):
     """``ServeEngine``'s decode program at Phi-3-mini's published widths,
     as the ``phi3-chat-backlog`` cell runs it: 4 slots of 2048 positions,
-    a position per slot.  The cache is donated and updated in place, and
-    weights, cache and the program's temporaries fit one 16 GB chip.  The
-    ``serve.*`` device readers take every op of the traced window, so no
-    op name needs pinning."""
+    a position per slot, the cache donated.  Returns (compiled, cache
+    shapes)."""
     import functools
 
     from repro.configs import ARCHS
@@ -122,9 +122,71 @@ def test_phi3_mini_serving_step_compiles_for_v5e_and_fits_one_chip(shape):
     compiled = jax.jit(functools.partial(serve_step, cfg=cfg),
                        donate_argnums=(2,)).lower(
         params, batch, cache, shape((B,), jnp.int32)).compile()
+    return compiled, cache
+
+
+def test_phi3_mini_serving_step_compiles_for_v5e_and_fits_one_chip(
+        phi3_serve_step):
+    """The cache is donated and updated in place, and weights, cache and
+    the program's temporaries fit one 16 GB chip.  The ``serve.*`` device
+    readers take every op of the traced window, so no op name needs
+    pinning."""
+    compiled, cache = phi3_serve_step
     mem = compiled.memory_analysis()
     cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
     assert mem.alias_size_in_bytes == cache_bytes
     held = mem.argument_size_in_bytes + mem.output_size_in_bytes - \
         mem.alias_size_in_bytes + mem.temp_size_in_bytes
     assert held < 16e9
+
+
+_MOVES = ("copy", "transpose", "dynamic-slice")
+
+
+def _kv_moves(hlo: str, sizes):
+    """The ops of a compiled program that copy, transpose or slice out an
+    array of one of ``sizes`` elements: ``copy``, ``transpose`` and
+    ``dynamic-slice`` instructions, and fusions the compiler named after
+    one (``constant_dynamic-slice_fusion.10``), outside the bodies of
+    fusions, where a slice only feeds its consumer and is never
+    materialised."""
+    comps, fused, cur = {}, set(), None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+            if " fusion(" in line:
+                fused.update(re.findall(r"calls=%([\w.\-]+)", line))
+    found = []
+    for name, lines in comps.items():
+        if name in fused:
+            continue
+        for line in lines:
+            m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                         r"([\w\-]+)\(", line)
+            if not m:
+                continue
+            op, dims = m.group(1), [int(d) for d in m.group(2).split(",") if d]
+            kind = m.group(3) if m.group(3) in _MOVES else next(
+                (k for k in _MOVES if m.group(3) == "fusion" and k in op), None)
+            if kind and math.prod(dims) in sizes:
+                found.append(f"{kind} {op} {dims}")
+    return found
+
+
+def test_phi3_mini_serving_step_writes_the_cache_in_place(phi3_serve_step):
+    """Each decode step writes one row a slot into each layer's K/V where
+    it lies: the program holds less than one layer's K plus V in
+    temporaries (a layer's K and V are 100,663,296 bytes), and copies,
+    transposes or slices out no whole K/V stack or layer.  A scan that
+    took the cache as ``xs`` and returned it as ``ys`` held 3.46 GB of
+    temporaries, sliced every layer out, copied it around the row
+    writes and copied the restacked cache into the donated buffer."""
+    compiled, cache = phi3_serve_step
+    k = cache["k"]
+    layer_bytes = 2 * (k.size // k.shape[0]) * k.dtype.itemsize
+    assert layer_bytes == 100_663_296
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+    assert _kv_moves(compiled.as_text(), {k.size, k.size // k.shape[0]}) == []
