@@ -23,9 +23,9 @@ from .obs import (Histogram, MetricsRegistry, RunReport, Trace, Tracer,
 from .skeleton import (GO_ON, AllToAll, EmitMany, Farm, FarmStats, Feedback,
                        FnNode, FusedNode, KeyBatch,
                        LatencyReservoir, LoweringError, MeshProgram, Pipeline,
-                       Skeleton, Source, Stage, ThreadProgram, as_skeleton,
+                       Skeleton, Source, Stage, as_skeleton,
                        compose, ff_node, fuse, lower, walk_stats)
-from .graph import Accelerator, Graph, Net, Token, build
+from .graph import Accelerator, Graph, Net, ThreadProgram, build
 from .procgraph import (ProcAccelerator, ProcGraph, ProcProgram,
                         pool_shutdown, pool_stats)
 from .a2a import A2AMeshProgram, stable_hash
@@ -56,7 +56,7 @@ __all__ = [
     "GO_ON", "EmitMany", "KeyBatch", "Accelerator", "Farm", "Feedback",
     "Graph", "Net",
     "Pipeline", "AllToAll",
-    "Skeleton", "Source", "Stage", "Token", "compose",
+    "Skeleton", "Source", "Stage", "compose",
     "LoweringError", "MeshProgram", "ThreadProgram", "as_skeleton", "build",
     "lower", "fuse", "FusedNode",
     "ProcAccelerator", "ProcGraph", "ProcProgram",

@@ -395,8 +395,8 @@ class _RebatchNode(ff_node):
 
 
 def _rebatch_ok_after(nxt: Optional[Skeleton]) -> bool:
-    # KeyBatch unpacking happens in StageVertex/ProcStageVertex inbound
-    # loops, the a2a scatter, and the caller-side result drain.  A farm's
+    # KeyBatch unpacking happens in StageVertex inbound loops, the a2a
+    # scatter, and the caller-side result drain.  A farm's
     # DispatchVertex routes payloads whole — never batch into one.
     return nxt is None or isinstance(nxt, (Stage, AllToAll, Feedback))
 

@@ -28,7 +28,7 @@ Four pieces:
     The accounting board shared by one reduction's partitions: bytes
     held / spill count / spilled bytes per partition plus one global
     backpressure-stall counter.  Plain Python counters on the threads
-    backend; on procs, :func:`~repro.core.a2a.build_proc_a2a` swaps in a
+    backend; on procs, ``ProcGraph.share_budget`` swaps in a
     :class:`~repro.core.shm.ShmCounters` board (``share``) before the
     vertices are pickled, every partition process writes only its own
     slots (single-writer per counter), and the runner copies the board
@@ -172,7 +172,7 @@ class MemoryBudget:
     def n_slots(self) -> int:
         return self.SLOTS_PER_PART * self.nparts + 1
 
-    # -- board lifecycle (procs backend; see build_proc_a2a) ----------------
+    # -- board lifecycle (procs backend; see ProcGraph.share_budget) --------
     def share(self, board: Any) -> None:
         """Swap in a shared counter board (``ShmCounters(self.n_slots)``).
         Carried-over local totals (from earlier runs of the same skeleton)

@@ -1,47 +1,41 @@
-"""Process-graph runtime — the ``procs`` backend of the skeleton IR.
+"""The procs runner — the skeleton IR's process backend.
 
-``graph.py`` runs every vertex as a *thread*, which keeps the runtime
-cheap but leaves pure-Python stages serialised behind the GIL: the
-FastFlow speedup story (paper Sec. 6) only materialises there for
-GIL-releasing kernels.  This module mirrors the same vertex machinery —
-source/stage vertices, dispatch + merge arbiters, tagged-token ordered
-farms, EOS propagation, loop quiescence for wrap-around edges — with each
-vertex a **spawned process** and every edge a :class:`~repro.core.shm.ShmRing`
-(the paper's SPSC ring over genuinely shared memory, cache-line-separated
-head/tail and all).  A farm of pure-Python ``svc`` functions finally
-scales with cores.
+The threads runner (``graph.py``) keeps the runtime cheap but leaves
+pure-Python stages serialised behind the GIL: the FastFlow speedup story
+(paper Sec. 6) only materialises there for GIL-releasing kernels.  This
+runner executes the same vertex protocol (:mod:`repro.core.vertex`) with
+each vertex a **spawned process** and every edge a
+:class:`~repro.core.shm.ShmRing` (the paper's SPSC ring over shared memory,
+cache-line-separated head/tail and all), so a farm of pure-Python ``svc``
+functions scales with cores.
 
-Construct map (vs the threads backend)
---------------------------------------
+What this runner decides (vs the threads runner)
+------------------------------------------------
 =============================  =============================================
 threads (``graph.py``)         procs (this module)
 =============================  =============================================
-``threading.Thread`` vertex    ``spawn``-ed ``multiprocessing.Process``
+``threading.Thread`` vertex    ``spawn``-ed, pooled ``multiprocessing``
+                               process
 ``SPSCQueue`` edge             ``ShmRing`` edge (pickled = attach by name)
-``Graph.results`` list         a results ring drained by the calling process
+``Graph.results`` list         a results ring per sink, drained by the caller
 ``Graph.failed`` list          a shared failure flag (:class:`ShmFlag`) + a
                                per-vertex control ring carrying ready/error
                                messages back to the caller
-``TagSpace.entered/retired``   ``ShmCounters`` board: two single-writer
-                               cache-line-separated u64s (dispatch writes
-                               ``entered``, merge writes ``retired``)
-``FarmStats`` (shared object)  per-arbiter local stats, merged at EOS and
-                               surfaced to the caller over a stats ring
-``sched.Scheduler`` policies   the same policy objects, driven from the
-                               dispatch arbiter's process (idle/steal and
-                               service-EWMA side-channels become ShmRings)
+``TagSpace.retired``           a one-slot ``ShmCounters`` board (the merge
+                               arbiter writes it, the dispatch arbiter reads)
+``FarmStats`` (shared object)  one per vertex, shipped over its control ring
+                               at EOS and folded into ``Farm.stats``
+50 µs idle sleep, one item     AIMD ``_Backoff``, up to ``_BATCH`` items per
+per ring per pass              ring per wake
 =============================  =============================================
 
-Single-writer discipline is preserved end to end: every ring has one
-producer and one consumer process; the quiescence board splits its
-counters by writer; the scheduling policy lives entirely inside the
-dispatch arbiter's process.  Even the *control plane* is shared-memory
-SPSC: ready/error messages ride a per-vertex control ring (vertex →
-caller) and the failure signal is a :class:`~repro.core.shm.ShmFlag`
-(idempotent multi-writer store).  Nothing on any path needs a lock —
-and, unlike ``multiprocessing``'s Queue/Event, every control primitive
-pickles as a plain segment attach, which is what lets vertices ride
-through a queue to **pooled** worker processes.
+Single-writer discipline holds end to end: every ring has one producer and
+one consumer process; the scheduling policy lives entirely inside the
+dispatch arbiter's process (worker side-channels — worksteal idle rings,
+costmodel service-EWMA rings — are ShmRings).  Even the *control plane* is
+shared-memory SPSC, and every control primitive pickles as a plain segment
+attach, which is what lets vertices ride through a queue to **pooled**
+worker processes.
 
 Spawn-pool reuse: starting a spawned interpreter costs ~0.1s (import of
 ``repro.core`` dominates); a program that lowers the same skeleton
@@ -78,60 +72,35 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 from .obs import VertexTracer, farm_stats_snapshot, qualname as _qualname
 from .sched import Scheduler, make_scheduler
 from .shm import ShmCounters, ShmFlag, ShmRing
-from .skeleton import (BACKENDS, GO_ON, AllToAll, EmitMany, Farm, FarmStats,
-                       Feedback, KeyBatch, LoweringError, Pipeline, Skeleton,
-                       Source, Stage, _FarmEmitMany, _coerce_metrics,
-                       _coerce_monitor, _coerce_tracer, _has_grained_stage,
-                       as_skeleton, ff_node, fuse as _fuse_pass, walk_stats)
+from .skeleton import (BACKENDS, Farm, FarmStats, KeyBatch, LoweringError,
+                       Skeleton, Source, _coerce_metrics, _coerce_monitor,
+                       _coerce_tracer, _fuse_for_lowering, as_skeleton,
+                       walk_stats)
 from .spsc import EOS, SPSCQueue
+from .vertex import (_POLL, MergeVertex, Runner, TagSpace, Vertex,
+                     WorkerVertex, build)
 
 __all__ = [
-    "ProcGraph", "ProcVertex", "ProcStageVertex", "ProcDispatchVertex",
-    "ProcWorkerVertex", "ProcMergeVertex", "build", "ProcProgram",
-    "ProcAccelerator", "pool_stats", "pool_shutdown",
+    "ProcGraph", "build", "ProcProgram", "ProcAccelerator", "pool_stats",
+    "pool_shutdown",
 ]
 
 _EMPTY = SPSCQueue._EMPTY
-_POLL = 0.000_05          # poll backoff (matches the SPSC blocking helpers)
 _BATCH = 256              # max items drained per ring per arbiter wake-up
-_ENTERED, _RETIRED = 0, 1  # quiescence-board slots (see ShmCounters)
-
-
-# Wire format: a farm token is a plain ``(tag, issued_at, payload)`` tuple,
-# not graph.py's Token dataclass — a tuple pickles in a third of the bytes
-# and time, and the procs backend has no speculation, so the ``duplicate``
-# flag would be dead weight on every hop.  ``issued_at`` is 0.0 except on a
-# 1-in-16 latency sample: clock reads are syscalls, expensive under
-# sandboxed kernels, and the latency reservoir only needs a sample.
-_LAT_SAMPLE = 15  # tag & _LAT_SAMPLE == 0 -> stamp and measure
-
-
-class _WorkerStats:
-    """A worker's final telemetry, sent down its own data ring just before
-    it acknowledges EOS — the single-writer way to get worker-side numbers
-    (the service-time EWMA) into the merge arbiter's FarmStats without any
-    shared object."""
-
-    __slots__ = ("index", "ewma")
-
-    def __init__(self, index: int, ewma: Optional[float]):
-        self.index = index
-        self.ewma = ewma
+# a token's ``issued`` stamp is taken on 1 tag in 16 (tag & _LAT_SAMPLE ==
+# 0): clock reads are syscalls, expensive under sandboxed kernels, and the
+# latency reservoir only needs a sample
+_LAT_SAMPLE = 15
 
 
 def _start_ctx():
     return mp.get_context(os.environ.get("REPRO_PROCS_START", "spawn"))
 
 
-class _Aborted(Exception):
-    """Internal: this vertex gave up because another vertex already failed
-    (its peer may be dead and its ring full — blocking would hang)."""
-
-
 class _Backoff:
-    """Adaptive idle backoff: 50µs doubling to 1ms while nothing moves.
+    """Adaptive idle backoff: 50µs doubling to 5ms while nothing moves.
 
-    The thread backend can poll at a fixed 50µs because a sleeping thread
+    The thread runner can poll at a fixed 50µs because a sleeping thread
     is nearly free; here every vertex is a *process* competing for the
     same cores as the workers, and on a small machine every arbiter
     wake-up is a context switch that preempts a worker mid-task (markedly
@@ -163,27 +132,9 @@ class _Backoff:
         self.delay = min(self.delay * 2, self._CAP)
 
 
-def _vertex_main(vertex: "ProcVertex") -> None:
+def _vertex_main(vertex: Vertex) -> None:
     """Child-process entry point (module-level: spawn pickles by name)."""
     vertex._run()
-
-
-class _CtlRing:
-    """Vertex-side endpoint of the control ring (vertex → caller).
-
-    Wraps the ring behind a ``put()`` so vertex code keeps its queue-ish
-    control surface; the ring never legitimately fills (≤ 3 messages per
-    vertex against capacity 8: ready, an optional error, an optional
-    EOS-time trace ship-back), so a timeout here means the caller is gone
-    and the message is dropped rather than wedging teardown."""
-
-    __slots__ = ("_ring",)
-
-    def __init__(self, ring: ShmRing):
-        self._ring = ring
-
-    def put(self, msg: Tuple) -> None:
-        self._ring.push_wait(msg, timeout=10.0)
 
 
 # ---------------------------------------------------------------------------
@@ -219,7 +170,7 @@ class _PoolWorker:
 
     __slots__ = ("jobq", "doneq", "proc", "busy")
 
-    def submit(self, vertex: "ProcVertex") -> None:
+    def submit(self, vertex: Vertex) -> None:
         # SimpleQueue.put pickles synchronously in THIS thread — an
         # unpicklable vertex raises here, before any bytes hit the pipe,
         # so the worker stays clean and reusable
@@ -331,697 +282,103 @@ atexit.register(pool_shutdown)
 
 
 # ---------------------------------------------------------------------------
-# vertices: one spawned process each, private ShmRing endpoints
+# the vertex side: what each vertex process carries of its runner
 # ---------------------------------------------------------------------------
-class ProcVertex:
-    """A network vertex: one process, private shared-memory SPSC endpoints.
+class _ProcPort:
+    """A vertex's ``rt`` on procs (see :class:`~repro.core.vertex.Runner`),
+    pickled into every vertex process with it: the graph's failure flag
+    and its trace settings, as plain picklable state.  Each vertex also
+    carries ``ctl``, its own control ring (vertex → caller): ready, an
+    optional error, and at EOS its trace lane and its ``FarmStats``."""
 
-    ``failed`` (:class:`ShmFlag`) and ``ctl`` (:class:`_CtlRing`) are
-    attached by :meth:`ProcGraph.add` — the control plane.  Both pickle
-    as segment attaches, so a vertex travels equally well through
-    ``Process`` args (direct spawn) and a pool worker's job queue.
-    ``cpus`` is an optional placement hint (see ``Scheduler.worker_cpus``)
-    applied best-effort on entry and undone by the pool between jobs.
-    """
+    drain = _BATCH
+    lat_mask = _LAT_SAMPLE
+    blocking_pop = False  # a blocked pop would never see the failure flag
 
-    def __init__(self, node: Optional[ff_node] = None, *,
-                 name: str = "ff-pvertex"):
-        self.node = node
-        self.name = name
-        # batch-aware nodes (SpillFold) take a whole KeyBatch in one svc
-        # call; everyone else gets it unpacked by the vertex loop
-        self._takes_batches = bool(getattr(node, "accepts_batches", False))
-        self.ins: List[ShmRing] = []
-        self.outs: List[ShmRing] = []
-        self.failed: Any = None   # ShmFlag, set by ProcGraph.add
-        self.ctl: Any = None      # _CtlRing, set by ProcGraph.add
-        self.cpus: Optional[Tuple[int, ...]] = None
-        # observability: ``path`` is the IR path assigned by build();
-        # trace config travels as plain ints (picklable through spawn and
-        # the pool job queue) — the VertexTracer itself is built child-
-        # side in _run() and shipped back over the control ring at EOS
-        self.path = ""
+    def __init__(self, flag: ShmFlag):
+        self.flag = flag
         self.trace_sample = 0     # 0 = tracing off
         self.trace_capacity = 0
-        self.tracer: Optional[VertexTracer] = None
 
-    # -- lifecycle (runs in the vertex's own process) -----------------------
-    def _run(self) -> None:
-        t_birth = 0.0
-        try:
-            if self.trace_sample:
-                self.tracer = VertexTracer(self.name, self.path,
-                                           sample=self.trace_sample,
-                                           capacity=self.trace_capacity)
-                t_birth = time.monotonic()
-                if self.node is not None and \
-                        getattr(self.node, "wants_tracer", False):
-                    self.node.tracer = self.tracer
-            if self.cpus:
-                try:
-                    os.sched_setaffinity(0, self.cpus)
-                except (AttributeError, OSError):  # hint only: never fatal
-                    pass
-            if self.node is not None:
-                self.node.svc_init()
-            self.ctl.put(("ready", self.name))
-            self._loop()
-        except _Aborted:
-            pass  # secondary shutdown; the original error is on the ctl queue
-        except BaseException as e:
-            self._report_error(e)
-        finally:
-            for q in self.outs:
-                self._push_abortable(q, EOS)
-            if self.node is not None:
-                try:
-                    self.node.svc_end()
-                except BaseException as e:  # pragma: no cover - defensive
-                    self._report_error(e)
-            tr = self.tracer
-            if tr is not None:
-                tr.instant("eos")
-                tr.span("life", t_birth, time.monotonic())
-                try:  # ship the lane home; best-effort at teardown
-                    self.ctl.put(("trace", self.name, self.path,
-                                  os.getpid(), tr.events, tr.dropped))
-                except Exception:  # pragma: no cover - caller gone
-                    pass
-            self._flush_stats()
-            for q in self.ins + self.outs:
-                q.close()
+    @property
+    def failed(self) -> bool:
+        return self.flag.is_set()
 
-    def _report_error(self, e: BaseException) -> None:
-        self.failed.set()
-        # the control ring pickles synchronously in put(), so an
-        # unpicklable exception would raise mid-report and LOSE the
-        # message — probe first and degrade to the repr
+    def waiter(self) -> _Backoff:
+        return _Backoff()
+
+    def open(self, v: Vertex) -> Optional[VertexTracer]:
+        # the lane is built child-side and shipped back at EOS (close)
+        if self.trace_sample:
+            v.tracer = VertexTracer(v.name, v.path, sample=self.trace_sample,
+                                    capacity=self.trace_capacity)
+        if v.cpus:
+            try:
+                os.sched_setaffinity(0, v.cpus)
+            except (AttributeError, OSError):  # hint only: never fatal
+                pass
+        return v.tracer
+
+    @staticmethod
+    def _put(v: Vertex, msg: Tuple) -> None:
+        # the ring never legitimately fills (<= 4 messages against 8
+        # slots): a timeout means the caller is gone, and the message is
+        # dropped rather than wedging teardown
+        v.ctl.push_wait(msg, timeout=10.0)
+
+    def ready(self, v: Vertex) -> None:
+        self._put(v, ("ready",))
+
+    def fail(self, v: Vertex, e: BaseException) -> None:
+        self.flag.set()
+        # push_wait pickles synchronously, so an unpicklable exception
+        # would raise mid-report and LOSE the message: probe first and
+        # degrade to the repr
         try:
             pickle.dumps(e)
         except Exception:
-            self.ctl.put(("error", self.name, repr(e), None))
+            e_ship = None
         else:
-            self.ctl.put(("error", self.name, repr(e), e))
+            e_ship = e
+        self._put(v, ("error", v.name, repr(e), e_ship))
 
-    def _flush_stats(self) -> None:
-        """Hook: arbiters surface their stats snapshots at shutdown."""
-
-    def _loop(self) -> None:
-        raise NotImplementedError
-
-    def _push_abortable(self, q: ShmRing, item: Any) -> bool:
-        """Blocking push that gives up once the graph has failed (the
-        ring's consumer may be dead; blocking would hang the teardown)."""
-        spins = 0
-        while not q.push(item):
-            spins += 1
-            if spins > 64:
-                if self.failed.is_set():
-                    return False
-                time.sleep(_POLL)
-        return True
-
-    def _deliver(self, payload: Any) -> None:
-        if not self._push_abortable(self.outs[0], payload):
-            raise _Aborted()
-
-
-class ProcStageVertex(ProcVertex):
-    """Generic vertex: nondeterministic fan-in merge, single-out.  With no
-    inbound edges it is a *source*: ``svc(None)`` until ``None`` (EOS) —
-    paper Fig. 2's emitter protocol, same as ``graph.StageVertex``.
-
-    ``batch > 1`` turns on the batched-emit wire format: outputs gather
-    in a local buffer and ship ``batch`` at a time through
-    :meth:`ShmRing.push_many` — one slot header and one tail store per
-    run of items instead of per item, which is what lets fine-grain
-    streams amortize the per-hop cost.  The buffer is flushed after the
-    node's EOS hook and *before* the EOS sentinel leaves this vertex, so
-    stream ordering (including the eosnotify release of keyed folds) is
-    byte-identical to the unbatched wire."""
-
-    def __init__(self, node: ff_node, *, name: str = "ff-pstage",
-                 batch: int = 1):
-        super().__init__(node, name=name)
-        self.batch = max(1, int(batch))
-        self._obuf: List[Any] = []
-
-    def _deliver(self, payload: Any) -> None:
-        if self.batch <= 1:
-            super()._deliver(payload)
-            return
-        self._obuf.append(payload)
-        if len(self._obuf) >= self.batch:
-            self._flush_batch()
-
-    def _flush_batch(self) -> None:
-        buf = self._obuf
-        if not buf:
-            return
-        out = self.outs[0]
-        backoff = _Backoff()
-        i = 0
-        while i < len(buf):
-            n = out.push_many(buf[i:] if i else buf)
-            if n:
-                i += n
-                continue
-            if self.failed.is_set():
-                self._obuf = []
-                raise _Aborted()
-            backoff.idle()
-        self._obuf = []
-
-    def _loop(self) -> None:
-        tr = self.tracer
-        if not self.ins:  # source
-            while True:
-                if tr is not None:
-                    t0 = tr.begin()
-                    out = self.node.svc(None)
-                    tr.end(t0, "svc")
-                else:
-                    out = self.node.svc(None)
-                if out is None or out is EOS:
-                    break
-                if out is GO_ON:
-                    continue
-                self._emit(out)
-            self._flush_eos()
-            self._flush_batch()
-            return
-        eos: set = set()
-        backoff = _Backoff()
-        while len(eos) < len(self.ins):
-            progress = False
-            for i, q in enumerate(self.ins):
-                if i in eos:
-                    continue
-                # batch-drain: a sleeping process pays ~1ms to wake, so one
-                # wake must move everything the ring has (bounded, for
-                # fairness across inbound edges)
-                for _ in range(_BATCH):
-                    item = q.pop()
-                    if item is _EMPTY:
-                        break
-                    progress = True
-                    if item is EOS:
-                        eos.add(i)
-                        break
-                    if type(item) is KeyBatch and not self._takes_batches:
-                        # batched wire format: unpack here so the node
-                        # still sees items (batching is transport only)
-                        for x in item:
-                            if tr is not None:
-                                t0 = tr.begin()
-                                out = self.node.svc(x)
-                                tr.end(t0, "svc")
-                            else:
-                                out = self.node.svc(x)
-                            if out is None or out is GO_ON:
-                                continue
-                            self._emit(out)
-                        continue
-                    if tr is not None:
-                        t0 = tr.begin()
-                        out = self.node.svc(item)
-                        tr.end(t0, "svc")
-                    else:
-                        out = self.node.svc(item)
-                    if out is None or out is GO_ON:
-                        continue  # filtered
-                    self._emit(out)
-            if progress:
-                backoff.reset()
-            else:
-                if self.failed.is_set():
-                    raise _Aborted()
-                # nothing inbound: ship the partial batch rather than
-                # holding the stream's tail hostage to the batch size
-                self._flush_batch()
-                backoff.idle()
-        self._flush_eos()
-        self._flush_batch()
-
-    def _flush_eos(self) -> None:
-        """EOS flush (eosnotify), mirroring ``graph.StageVertex``: the node
-        may emit buffered state into the stream before this vertex's EOS
-        goes out — keyed folds and window operators release here."""
-        out = self.node.svc_eos()
-        if out is not None and out is not GO_ON:
-            self._emit(out)
-
-    def _emit(self, out: Any) -> None:
-        if type(out) is KeyBatch:  # one wire message; consumers unpack
-            if out:
-                self._deliver(out)
-            return
-        if isinstance(out, EmitMany):  # multi-emit (e.g. a reorder flush)
-            for o in out:
-                self._emit(o)
-            return
-        self._deliver(out)
-
-
-class ProcDispatchVertex(ProcVertex):
-    """The farm's Emitter arbiter as a process (paper Figs. 1-2).
-
-    Drives the same pluggable :class:`~repro.core.sched.Scheduler` policy
-    hierarchy as the thread backend — the policy object (and all its
-    state: worksteal backlogs, costmodel EWMAs) lives entirely in this
-    arbiter's process, so the single-writer discipline is untouched.
-    Worker side-channels (worksteal idle rings, costmodel service-EWMA
-    rings) are ShmRings, drained here.  When ``loop_ring`` is set this
-    vertex is the loop master: quiescence reads the merge arbiter's
-    ``retired`` counter off the shared :class:`ShmCounters` board.
-    """
-
-    def __init__(self, sched: Scheduler, node: Optional[ff_node] = None, *,
-                 loop_ring: Optional[ShmRing] = None,
-                 loop_board: Optional[ShmCounters] = None,
-                 service_rings: Optional[List[ShmRing]] = None,
-                 stats_out: Optional[ShmRing] = None,
-                 live_board: Optional[ShmCounters] = None,
-                 name: str = "ff-emitter"):
-        super().__init__(node, name=name)
-        self.sched = sched
-        self.loop_ring = loop_ring
-        self.loop_board = loop_board
-        self.live_board = live_board  # monitor tap: slot 0 = emitted
-        self.service_rings = service_rings or []
-        self.stats_out = stats_out  # dispatch -> merge stats hand-off
-        self.stats = FarmStats()
-        self._next_tag = 0
-        self._entered = 0
-        self._stash: List[Any] = []
-
-    def _drain_service(self) -> None:
-        """Fold worker service-EWMA updates into the policy's stats (the
-        cross-process replacement for workers writing ``FarmStats``
-        directly — arbiter-side state stays in the arbiter process)."""
-        for ring in self.service_rings:
-            while True:
-                upd = ring.pop()
-                if upd is _EMPTY:
-                    break
-                self.sched.observe_service(upd[0], upd[1])
-
-    def _push_with_loop_drain(self, q: ShmRing, tok: tuple) -> None:
-        """Blocking push that keeps draining the wrap-around ring while
-        the target worker ring is full (breaks cyclic backpressure, same
-        argument as ``graph.DispatchVertex._push_with_loop_drain``)."""
-        if q.push(tok):
-            return  # fast path: no stall, no clock read
-        tr = self.tracer
-        t0 = time.monotonic() if tr is not None else 0.0
-        spins = 0
-        while not q.push(tok):
-            if self.loop_ring is not None:
-                item = self.loop_ring.pop()
-                if item is not _EMPTY:
-                    self._stash.append(item)
-                    continue
-            spins += 1
-            if spins > 64:
-                if self.failed.is_set():
-                    raise _Aborted()
-                time.sleep(_POLL)
-        if tr is not None:
-            tr.span("stall", t0, time.monotonic())
-
-    def _emit_to(self, widx: int, tok: tuple) -> None:
-        self._push_with_loop_drain(self.outs[widx], tok)
-
-    def _dispatch(self, task: Any) -> None:
-        tag = self._next_tag
-        issued = time.monotonic() if tag & _LAT_SAMPLE == 0 else 0.0
-        tok = (tag, issued, task)
-        self._next_tag += 1
-        if self.loop_board is not None:
-            self._entered += 1
-            self.loop_board.add(_ENTERED, 1)
-        self.sched.place(tok, self._emit_to)
-        self.stats.tasks_emitted += 1
-        if self.live_board is not None:
-            self.live_board.add(0, 1)  # single writer: this arbiter only
-        # backpressure for token-holding policies (worksteal): stop intake
-        # while the policy backlog is over its high-water mark
-        hw = self.sched.high_water
-        if hw is not None and self.sched.pending() > hw:
-            tr = self.tracer
-            t0 = time.monotonic() if tr is not None else 0.0
-            spins = 0
-            while self.sched.pending() > hw:
-                if self.sched.pump():
-                    continue
-                if self.failed.is_set():
-                    raise _Aborted()
-                if self.loop_ring is not None:
-                    item = self.loop_ring.pop()
-                    if item is not _EMPTY:
-                        self._stash.append(item)
-                        continue
-                spins += 1
-                if spins > 64:
-                    time.sleep(_POLL)
+    def close(self, v: Vertex) -> None:
+        tr = v.tracer
+        try:  # best-effort at teardown: the caller may be gone
             if tr is not None:
-                tr.span("stall", t0, time.monotonic())
-
-    def _quiescent(self) -> bool:
-        """entered == retired and the wrap-around ring is drained.  Read
-        order matters: ``retired`` first, then the ring — the merge
-        arbiter pushes looped-back tasks *before* bumping ``retired``."""
-        retired = self.loop_board.get(_RETIRED)
-        return self._entered == retired and self.loop_ring.empty()
-
-    def _loop(self) -> None:
-        self.sched.bind(self.outs, self.stats)
-        tr = self.tracer
-        steals0 = self.stats.steals if tr is not None else 0
-        backoff = _Backoff()
-        if self.node is not None and not self.ins:
-            # source mode: the emitter node generates the stream
-            while True:
-                self._drain_service()
-                if tr is not None:
-                    t0 = tr.begin()
-                    task = self.node.svc(None)
-                    tr.end(t0, "svc")
-                else:
-                    task = self.node.svc(None)
-                if task is None or task is EOS:
-                    break
-                if task is GO_ON:
-                    continue
-                self._dispatch(task)
-                self.sched.pump()
-                if tr is not None and self.stats.steals != steals0:
-                    tr.instant("steal",
-                               {"count": self.stats.steals - steals0})
-                    steals0 = self.stats.steals
-                if self.loop_ring is not None:
-                    while True:
-                        item = self.loop_ring.pop()
-                        if item is _EMPTY:
-                            break
-                        self._dispatch(item)
-                        if tr is not None:
-                            tr.tick("loop")
-            # source exhausted; drain the loop to quiescence
-            while self.loop_ring is not None:
-                progress = self.sched.pump()
-                while self._stash:
-                    self._dispatch(self._stash.pop(0))
-                    progress = True
-                while True:
-                    item = self.loop_ring.pop()
-                    if item is _EMPTY:
-                        break
-                    progress = True
-                    self._dispatch(item)
-                    if tr is not None:
-                        tr.tick("loop")
-                if not self._stash and not self.sched.pending() \
-                        and self._quiescent():
-                    break
-                if self.failed.is_set():
-                    raise _Aborted()
-                if progress:
-                    backoff.reset()
-                elif self.sched.pending():
-                    time.sleep(0)  # yield: the policy still holds tokens
-                else:
-                    backoff.idle()
-        else:
-            eos: set = set()
-            while True:
-                progress = self.sched.pump()
-                self._drain_service()
-                if tr is not None and self.stats.steals != steals0:
-                    tr.instant("steal",
-                               {"count": self.stats.steals - steals0})
-                    steals0 = self.stats.steals
-                # wrap-around tokens first: looped-back work is older
-                while self._stash:
-                    self._dispatch(self._stash.pop(0))
-                    progress = True
-                if self.loop_ring is not None:
-                    while True:
-                        item = self.loop_ring.pop()
-                        if item is _EMPTY:
-                            break
-                        progress = True
-                        self._dispatch(item)
-                        if tr is not None:
-                            tr.tick("loop")
-                for i, q in enumerate(self.ins):
-                    if i in eos:
-                        continue
-                    for _ in range(_BATCH):  # amortise the wake-up cost
-                        item = q.pop()
-                        if item is _EMPTY:
-                            break
-                        progress = True
-                        if item is EOS:
-                            eos.add(i)
-                            break
-                        if self.node is not None:
-                            # emitter node as per-item scheduler/filter
-                            if tr is not None:
-                                t0 = tr.begin()
-                                item = self.node.svc(item)
-                                tr.end(t0, "svc")
-                            else:
-                                item = self.node.svc(item)
-                            if item is None or item is GO_ON:
-                                continue
-                        self._dispatch(item)
-                if len(eos) == len(self.ins) and not self._stash \
-                        and not self.sched.pending():
-                    if self.loop_ring is None or self._quiescent():
-                        break
-                if self.failed.is_set():
-                    raise _Aborted()  # a vertex died: no quiescence possible
-                if progress:
-                    backoff.reset()
-                elif self.sched.pending():
-                    time.sleep(0)  # yield: the policy still holds tokens
-                else:
-                    backoff.idle()
-        # flush tokens still held by the policy (worksteal backlogs)
-        # before the EOS goes out behind them
-        while self.sched.pending():
-            if self.failed.is_set():
-                raise _Aborted()
-            if not self.sched.pump():
-                time.sleep(0)
-
-    def _flush_stats(self) -> None:
-        # hand the dispatch-side counters to the merge arbiter, which owns
-        # the farm's merged FarmStats snapshot (SPSC: one producer, one
-        # consumer; the data rings to the workers are already EOS'd)
-        if self.stats_out is not None:
-            self.stats_out.push_wait(self.stats, timeout=2.0)
-            self.stats_out.close()
+                self._put(v, ("trace", v.name, v.path, os.getpid(),
+                              tr.events, tr.dropped))
+            if v.stats is not None:
+                self._put(v, ("stats", v.stats))
+        except Exception:  # pragma: no cover
+            pass
+        for q in v.ins + v.outs:
+            q.close()
 
 
-class ProcWorkerVertex(ProcVertex):
-    """Farm worker process: one inbound and one outbound ring, tags carried
-    through untouched.  With an ``idle_ring`` (worksteal) it advertises
-    idleness to the arbiter; with a ``service_ring`` (costmodel) it streams
-    its service-time EWMA back — both SPSC ShmRings, worker → arbiter."""
+class _BoardTags:
+    """A wrap-around farm's :class:`~repro.core.vertex.TagSpace` on procs:
+    ``retired`` lives in a one-slot shared board (the merge arbiter is its
+    one writer, the dispatch arbiter reads it for quiescence — stores are
+    ordered after the looped-back pushes, on x86-TSO store order)."""
 
-    def __init__(self, node: ff_node, index: int, *,
-                 idle_ring: Optional[ShmRing] = None,
-                 service_ring: Optional[ShmRing] = None,
-                 name: str = "ff-worker"):
-        super().__init__(node, name=name)
-        self.index = index
-        self.idle_ring = idle_ring
-        self.service_ring = service_ring
+    inflight = done = None  # no speculation on procs
 
-    def _loop(self) -> None:
-        q_in, q_out = self.ins[0], self.outs[0]
-        tr = self.tracer
-        record = self.service_ring is not None
-        ewma: Optional[float] = None
-        backoff = _Backoff()
-        signaled = False
-        spins = 0
-        while True:
-            tok = q_in.pop()
-            if tok is _EMPTY:
-                if self.idle_ring is not None and \
-                        (not signaled or spins % 512 == 511):
-                    # steal side-channel: advertise idleness (re-advertise
-                    # periodically — a signal consumed while the arbiter
-                    # had nothing to give must not strand this worker)
-                    signaled = self.idle_ring.push(self.index) or signaled
-                spins += 1
-                if spins > 64:
-                    if self.failed.is_set():
-                        raise _Aborted()
-                    backoff.idle()
-                continue
-            signaled = False
-            spins = 0
-            backoff.reset()
-            if tok is EOS:
-                if record:
-                    self._push_abortable(q_out, _WorkerStats(self.index, ewma))
-                return
-            tag, issued, payload = tok
-            tb = tr.begin() if tr is not None else 0.0
-            if record:
-                t0 = time.monotonic()
-                result = self.node.svc(payload)
-                dt = time.monotonic() - t0
-                ewma = dt if ewma is None else 0.8 * ewma + 0.2 * dt
-                self.service_ring.push((self.index, ewma))  # drop-if-full ok
-            else:
-                result = self.node.svc(payload)
-            if tr is not None:
-                tr.end(tb, "svc")
-            if not self._push_abortable(q_out, (tag, issued, result)):
-                raise _Aborted()
+    def __init__(self, board: ShmCounters):
+        self._board = board
 
+    @property
+    def retired(self) -> int:
+        return self._board.get(0)
 
-class ProcMergeVertex(ProcVertex):
-    """The farm's Collector arbiter as a process (paper Figs. 1-2).
-
-    Optional reorder-by-tag (``ordered``), optional collector node,
-    optional wrap-around routing (``feedback``), as in
-    ``graph.MergeVertex`` — minus the dedup-by-tag bookkeeping: the procs
-    backend rejects speculation at lowering, so duplicates are impossible
-    by construction and a per-tag seen-dict would only be an unbounded
-    leak in a long-lived farm.  Owns the farm's merged :class:`FarmStats`:
-    collects its own side, folds in the dispatch side from the ``d2m``
-    stats ring at EOS, and surfaces the snapshot to the calling process
-    over the farm's stats ring."""
-
-    def __init__(self, node: Optional[ff_node] = None, *,
-                 ordered: bool = False,
-                 loop_ring: Optional[ShmRing] = None,
-                 loop_board: Optional[ShmCounters] = None,
-                 feedback: Optional[Callable[[Any], Tuple[Any, Iterable[Any]]]] = None,
-                 stats_in: Optional[ShmRing] = None,
-                 stats_out: Optional[ShmRing] = None,
-                 live_board: Optional[ShmCounters] = None,
-                 name: str = "ff-collector"):
-        super().__init__(node, name=name)
-        self.ordered = ordered
-        self.loop_ring = loop_ring
-        self.loop_board = loop_board
-        self.live_board = live_board  # monitor tap: slot 1 = collected
-        self.feedback = feedback
-        self.stats_in = stats_in    # dispatch -> merge counter hand-off
-        self.stats_out = stats_out  # merge -> caller snapshot
-        self.stats = FarmStats()
-
-    def _loop(self) -> None:
-        st = self.stats
-        eos: set = set()
-        next_tag = 0
-        reorder: Dict[int, Any] = {}
-        backoff = _Backoff()
-        while len(eos) < len(self.ins):
-            progress = False
-            for i, q in enumerate(self.ins):
-                if i in eos:
-                    continue
-                for _ in range(_BATCH):  # amortise the wake-up cost
-                    tok = q.pop()
-                    if tok is _EMPTY:
-                        break
-                    progress = True
-                    if tok is EOS:
-                        eos.add(i)
-                        break
-                    if isinstance(tok, _WorkerStats):
-                        if tok.ewma is not None:
-                            st.service_ewma[tok.index] = tok.ewma
-                        continue
-                    tag, issued, payload = tok
-                    st.tasks_collected += 1
-                    if self.live_board is not None:
-                        self.live_board.add(1, 1)  # single writer: merge only
-                    st.per_worker[i] = st.per_worker.get(i, 0) + 1
-                    if issued:
-                        st.latencies.append(time.monotonic() - issued)
-                    if self.ordered:
-                        reorder[tag] = payload
-                        while next_tag in reorder:
-                            self._complete(reorder.pop(next_tag))
-                            next_tag += 1
-                    else:
-                        self._complete(payload)
-            if progress:
-                backoff.reset()
-            else:
-                if self.failed.is_set():
-                    raise _Aborted()
-                backoff.idle()
-        # flush any residue (can only happen if tags were skipped upstream)
-        for t in sorted(reorder):
-            self._complete(reorder.pop(t))
-
-    def _complete(self, payload: Any) -> None:
-        if payload is GO_ON:
-            self._retire()
-            return
-        tr = self.tracer
-        if self.node is not None:
-            if tr is not None:
-                t0 = tr.begin()
-                payload = self.node.svc(payload)
-                tr.end(t0, "svc")
-            else:
-                payload = self.node.svc(payload)
-            if payload is None or payload is GO_ON:
-                self._retire()
-                return
-        if self.feedback is not None:
-            emit, new_tasks = self.feedback(payload)
-            # push wrap-around tasks BEFORE retiring the token: the
-            # dispatch arbiter's quiescence check relies on this ordering
-            # (now across processes, on x86-TSO store order).
-            for t in new_tasks:
-                if not self._push_abortable(self.loop_ring, t):
-                    raise _Aborted()
-                if tr is not None:
-                    tr.tick("loop")
-            self._retire()
-            if emit is None:
-                return
-            payload = emit
-        else:
-            self._retire()
-        if isinstance(payload, _FarmEmitMany):
-            for p in payload:
-                self._deliver(p)
-            return
-        self._deliver(payload)
-
-    def _retire(self) -> None:
-        if self.loop_board is not None:
-            self.loop_board.add(_RETIRED, 1)
-
-    def _flush_stats(self) -> None:
-        if self.stats_in is not None:
-            # fold the dispatch side in (it flushes right after EOS'ing
-            # the workers, so it is normally already here)
-            disp = self.stats_in.pop_wait(timeout=2.0)
-            if disp is not _EMPTY and isinstance(disp, FarmStats):
-                _fold_stats(self.stats, disp)
-            self.stats_in.close()
-        if self.stats_out is not None:
-            self.stats_out.push_wait(self.stats, timeout=2.0)
-            self.stats_out.close()
+    @retired.setter
+    def retired(self, n: int) -> None:
+        self._board.set(0, n)
 
 
 def _fold_stats(dst: FarmStats, src: FarmStats) -> None:
     """Merge one FarmStats snapshot into another (disjoint writers: each
-    counter was filled by exactly one arbiter/worker, so += is exact)."""
+    counter was filled by exactly one vertex, so += is exact)."""
     dst.tasks_emitted += src.tasks_emitted
     dst.tasks_collected += src.tasks_collected
     dst.duplicates_issued += src.duplicates_issued
@@ -1039,14 +396,14 @@ def _fold_stats(dst: FarmStats, src: FarmStats) -> None:
 
 
 # ---------------------------------------------------------------------------
-# the graph: spawned vertices + shared-memory edges, driven by the caller
+# the runner: spawned vertices + shared-memory edges, driven by the caller
 # ---------------------------------------------------------------------------
-class ProcGraph:
+class ProcGraph(Runner):
     """A streaming network of processes over shared-memory SPSC rings.
 
     Mirrors :class:`graph.Graph`'s API (``add``/``connect``/``run``/
     ``wait``) with process semantics: the caller is the single consumer of
-    the results ring, errors arrive over per-vertex control rings, and
+    the results rings, errors arrive over per-vertex control rings, and
     ``wait`` tears everything down — returns pooled workers (or joins /
     terminates direct-spawned ones) and unlinks every shared-memory
     segment, so no run leaks processes or ``/dev/shm`` entries.
@@ -1057,6 +414,9 @@ class ProcGraph:
     declared ``grain=`` as its batch size; ``pool`` selects spawn-pool
     reuse (default: on unless ``REPRO_PROCS_POOL=0``)."""
 
+    speculation = False
+    drain = _BATCH  # a caller-side merge arbiter drains like a vertex
+
     def __init__(self, *, capacity: int = 512, slot_size: int = 248,
                  zero_copy: bool = True, batch: Any = None,
                  pool: Optional[bool] = None):
@@ -1066,16 +426,18 @@ class ProcGraph:
         self.batch = batch
         self._ctx = _start_ctx()
         self._pool = _get_pool(self._ctx) if _pool_enabled(pool) else None
-        self.vertices: List[ProcVertex] = []
+        self.vertices: List[Vertex] = []
         self.results: List[Any] = []
         self.failed: List[BaseException] = []
         self._rings: List[Any] = []          # every segment, for unlink
         self.failed_event = ShmFlag()
         self._rings.append(self.failed_event)
+        self._port = _ProcPort(self.failed_event)
         self._ctl_rings: List[ShmRing] = []  # one per vertex, vertex->caller
+        # the Farm whose stats each farm vertex's shipped snapshot joins
+        self._homes: Dict[Vertex, Farm] = {}
         self._procs: List[Any] = []
         self._pool_workers: List[_PoolWorker] = []
-        self._farm_stats: List[Tuple[Farm, ShmRing]] = []
         # post-run hooks (builders register them): read telemetry boards
         # back into the IR node's stats BEFORE shared memory is unlinked
         self.finalizers: List[Callable[[], None]] = []
@@ -1088,19 +450,16 @@ class ProcGraph:
         # its sampling config; lanes come home over the control rings at
         # EOS and are absorbed here (caller side) by _on_ctl
         self.tracer = None
-        # live monitoring: when live_telemetry is set before build(), each
-        # farm gets a 2-slot single-writer ShmCounters board (slot 0 =
-        # emitted by the dispatch arbiter, slot 1 = collected by the merge
-        # arbiter) registered here by farm qualname — the Monitor reads
-        # them caller-side with peek(), no ring traffic
-        self.live_telemetry = False
+        # live monitoring: with live_telemetry set before build(), each
+        # farm gets a 2-slot single-writer board (slot 0 = emitted by the
+        # dispatch arbiter, slot 1 = collected by the merge arbiter)
+        # registered here by farm qualname — the Monitor reads them
+        # caller-side with peek(), no ring traffic
         self.live_boards: Dict[str, ShmCounters] = {}
 
     # -- construction -------------------------------------------------------
-    def channel(self, capacity: Optional[int] = None,
-                slot_size: Optional[int] = None) -> ShmRing:
-        ring = ShmRing(capacity or self.capacity,
-                       slot_size or self.slot_size,
+    def channel(self, capacity: Optional[int] = None) -> ShmRing:
+        ring = ShmRing(capacity or self.capacity, self.slot_size,
                        zero_copy=self.zero_copy)
         self._rings.append(ring)
         return ring
@@ -1110,68 +469,61 @@ class ProcGraph:
         self._rings.append(board)
         return board
 
+    def loop_channel(self, capacity: int) -> ShmRing:
+        return self.channel(min(capacity, 4096))  # one segment per ring
+
+    def tags(self, farm: Farm) -> Any:
+        return _BoardTags(self.counters(1)) if farm.feedback is not None \
+            else TagSpace()
+
+    def farm_stats(self, farm: Farm, v: Vertex) -> FarmStats:
+        self._homes[v] = farm
+        return FarmStats()
+
+    def live_board(self, path: str) -> Optional[ShmCounters]:
+        if not self.live_telemetry:
+            return None  # a monitorless lowering allocates nothing extra
+        board = self.live_boards[_qualname("ff-farm", path)] = self.counters(2)
+        return board
+
+    def service_ring(self) -> ShmRing:
+        return self.channel(64)
+
+    def terminal(self, v: Vertex) -> None:
+        v.outs.append(self.results_ring())
+
     def batch_for(self, grain: Optional[int]) -> int:
-        """Resolve the effective emit-batch size for a stage declaring
-        ``grain`` (1 = unbatched; see the class docstring)."""
+        """The emit-batch size for a stage declaring ``grain`` (1 =
+        unbatched; see the class docstring)."""
         if self.batch is None:
             return 1
         if self.batch == "grain":
             return int(grain) if grain else 1
         return max(1, int(self.batch))
 
-    def sample_high_water(self, into: Dict[str, int]) -> Dict[str, int]:
-        """Profile tap, mirroring :meth:`graph.Graph.sample_high_water`:
-        record each vertex's current outbound queue depth into ``into``,
-        keeping the per-name maximum across calls.  The caller owns the
-        ring segments, so ``len()`` (a read of the shared head/tail
-        counters) works cross-process without touching the stream.  Keys
-        are IR-path qualified (``name@path``), mirroring the threads
-        backend, so merged reports cannot collide."""
-        for v in self.vertices:
-            depth = 0
-            for ring in v.outs:
-                try:
-                    depth = max(depth, len(ring))
-                except (TypeError, OSError, ValueError):
-                    pass  # ValueError: memoryview released mid-teardown
-            key = _qualname(v.name, v.path)
-            if depth > into.get(key, -1):
-                into[key] = depth
-        return into
+    def share_budget(self, budget: Any, stats: FarmStats) -> None:
+        if not (hasattr(budget, "share") and hasattr(budget, "n_slots")):
+            return
+        # swap in a shared counter board NOW, before run() pickles the
+        # vertices: every partition process attaches the same segment
+        # (ShmCounters travels by name) and writes only its own slots
+        budget.share(self.counters(budget.n_slots))
 
-    def sample_depths(self, into: Dict[str, int]) -> Dict[str, int]:
-        """Live-monitor tap, mirroring :meth:`graph.Graph.sample_depths`:
-        the *instantaneous* outbound depth per vertex (overwrite
-        semantics — one call = one timeline frame).  Safe against the
-        monitor thread racing ``_cleanup()``: a ring whose segment is
-        already unlinked reads as depth 0, never raises."""
-        for v in self.vertices:
-            depth = 0
-            for ring in v.outs:
-                try:
-                    depth = max(depth, len(ring))
-                except (TypeError, OSError, ValueError):
-                    pass
-            into[_qualname(v.name, v.path)] = depth
-        return into
+        def collect() -> None:
+            budget.collect()  # copy the board out before it is unlinked
+            budget.fold_into(stats)
+        self.finalizers.append(collect)
 
-    def add(self, v: ProcVertex) -> ProcVertex:
-        v.failed = self.failed_event
+    def add(self, v: Vertex) -> Vertex:
+        v.rt = self._port
         # control edge: SPSC (this vertex produces, the caller consumes);
         # plain pickle — identity and fidelity over speed off the data path
         ring = ShmRing(8, 512, zero_copy=False)
         self._rings.append(ring)
         self._ctl_rings.append(ring)
-        v.ctl = _CtlRing(ring)
+        v.ctl = ring
         self.vertices.append(v)
         return v
-
-    def connect(self, src: ProcVertex, dst: ProcVertex, *,
-                capacity: Optional[int] = None) -> ShmRing:
-        ring = self.channel(capacity)
-        src.outs.append(ring)
-        dst.ins.append(ring)
-        return ring
 
     def results_ring(self) -> ShmRing:
         """A terminal edge: produced by ONE sink vertex, consumed by the
@@ -1184,17 +536,13 @@ class ProcGraph:
         self._results_rings.append(ring)
         return ring
 
-    def register_farm_stats(self, farm: Farm, ring: ShmRing) -> None:
-        self._farm_stats.append((farm, ring))
-
     # -- execution ----------------------------------------------------------
     def run(self) -> "ProcGraph":
         assert not self._procs, "graph already running"
         tr = self.tracer
-        if tr is not None:
-            for v in self.vertices:
-                v.trace_sample = tr.sample
-                v.trace_capacity = tr.capacity
+        if tr is not None:  # before the vertices (and the port) pickle
+            self._port.trace_sample = tr.sample
+            self._port.trace_capacity = tr.capacity
         pickling_errors = (pickle.PicklingError, AttributeError, TypeError)
         if self._pool is not None:
             for v in self.vertices:
@@ -1268,7 +616,7 @@ class ProcGraph:
         self._eos_seen = len(self._eos_rings) == len(self._results_rings)
         return self._eos_seen
 
-    def _on_ctl(self, msg: Tuple) -> None:
+    def _on_ctl(self, v: Vertex, msg: Tuple) -> None:
         if msg[0] == "ready":
             self._ready += 1
         elif msg[0] == "error":
@@ -1279,14 +627,16 @@ class ProcGraph:
             _, name, path, pid, events, dropped = msg
             if self.tracer is not None:
                 self.tracer.absorb(name, path, pid, events, dropped)
+        elif msg[0] == "stats":
+            _fold_stats(self._homes[v].stats, msg[1])
 
     def _drain_ctl(self) -> None:
-        for ring in self._ctl_rings:
+        for v, ring in zip(self.vertices, self._ctl_rings):
             while True:
                 msg = ring.pop()
                 if msg is _EMPTY:
                     break
-                self._on_ctl(msg)
+                self._on_ctl(v, msg)
 
     def _all_vertices_exited(self) -> bool:
         if self._pool is not None:
@@ -1372,10 +722,6 @@ class ProcGraph:
         return self.run().wait(timeout)
 
     def _collect_stats(self) -> None:
-        for farm, ring in self._farm_stats:
-            snap = ring.pop()
-            if snap is not _EMPTY and isinstance(snap, FarmStats):
-                _fold_stats(farm.stats, snap)
         while self.finalizers:
             self.finalizers.pop()()  # runs before _cleanup unlinks boards
 
@@ -1442,118 +788,6 @@ class ProcGraph:
             ring.unlink()
 
 
-# ---------------------------------------------------------------------------
-# procs lowering: IR tree -> spawned vertices + shared-memory rings
-# ---------------------------------------------------------------------------
-def build(skel: Skeleton, g: ProcGraph, in_ring: Optional[Any],
-          terminal: bool, path: str = "") -> Optional[Any]:
-    """Wire a skeleton IR node into ``g`` — the procs twin of
-    :func:`repro.core.graph.build`, one spawned process per vertex.
-    ``in_ring`` may be one ring or a list (a terminal all-to-all row).
-    ``path`` is the node's IR path, carried onto every vertex so
-    telemetry keys match the threads backend's."""
-    from .graph import ring_list
-
-    if isinstance(skel, AllToAll):
-        from .a2a import build_proc_a2a  # lazy: a2a imports this module
-        return build_proc_a2a(skel, g, ring_list(in_ring), terminal,
-                              path=path)
-
-    if isinstance(skel, Source):
-        assert in_ring is None, "Source cannot have an upstream edge"
-        return build(Stage(skel.node, name=skel.name, grain=skel.grain,
-                           capacity=skel.capacity), g, None, terminal, path)
-
-    if isinstance(skel, Pipeline):
-        ring = in_ring
-        last = len(skel.stages) - 1
-        for i, s in enumerate(skel.stages):
-            p = f"{path}.{i}" if path else str(i)
-            if i == last:
-                return build(s, g, ring, terminal, p)
-            ring = build(s, g, ring, False, p)
-
-    if isinstance(skel, Feedback):
-        # predicate loop -> tagger + wrap-around farm + reorder (Sec. 5)
-        return build(skel.as_thread_net(), g, in_ring, terminal, path)
-
-    if isinstance(skel, Farm):
-        if skel.speculative:
-            raise LoweringError(
-                "speculative straggler re-issue is threads-only (its tag "
-                "bookkeeping is shared between the two arbiters); use "
-                "lower(skel, 'threads') for it")
-        cap = skel.capacity or g.capacity
-        has_loop = skel.feedback is not None
-        # the wrap-around ring: merge -> dispatch, plus the quiescence
-        # board (entered/retired, one single-writer counter each)
-        loop_ring = (g.channel(min(skel.feedback_capacity, 4096))
-                     if has_loop else None)
-        board = g.counters(2) if has_loop else None
-        d2m = g.channel(4)          # dispatch -> merge stats hand-off
-        stats_ring = g.channel(4)   # merge -> caller FarmStats snapshot
-        g.register_farm_stats(skel, stats_ring)
-        live = None
-        if getattr(g, "live_telemetry", False):
-            live = g.counters(2)    # monitor tap: emitted / collected
-            g.live_boards[_qualname("ff-farm", path)] = live
-
-        sched = make_scheduler(skel.scheduling)
-        service_rings: List[ShmRing] = []
-        disp = g.add(ProcDispatchVertex(
-            sched, skel.emitter, loop_ring=loop_ring, loop_board=board,
-            service_rings=service_rings, stats_out=d2m, live_board=live))
-        disp.path = path
-        if in_ring is not None:
-            disp.ins.extend(ring_list(in_ring))
-        else:
-            assert skel.emitter is not None, \
-                "a standalone farm needs an emitter (or compose it after a Source)"
-
-        merge = g.add(ProcMergeVertex(
-            skel.collector, ordered=skel.ordered, loop_ring=loop_ring,
-            loop_board=board, feedback=skel.feedback,
-            stats_in=d2m, stats_out=stats_ring, live_board=live))
-        merge.path = path
-        for i, node in enumerate(skel.worker_nodes):
-            idle = sched.worker_channel(i, g.channel)
-            # a live monitor consumes the EWMAs too: arm the service
-            # rings so the detach-time frame carries real service times
-            service = (g.channel(64)
-                       if sched.needs_service_stats
-                       or getattr(g, "live_telemetry", False) else None)
-            if service is not None:
-                service_rings.append(service)
-            w = g.add(ProcWorkerVertex(node, i, idle_ring=idle,
-                                       service_ring=service,
-                                       name=f"ff-worker-{i}"))
-            w.path = path
-            w.cpus = sched.worker_cpus(i, len(skel.worker_nodes))
-            g.connect(disp, w, capacity=cap)
-            g.connect(w, merge, capacity=cap)
-        if terminal:
-            merge.outs.append(g.results_ring())
-            return None
-        ring = g.channel(skel.capacity)
-        merge.outs.append(ring)
-        return ring
-
-    if isinstance(skel, Stage):
-        v = g.add(ProcStageVertex(skel.node, name=skel.name,
-                                  batch=g.batch_for(skel.grain)))
-        v.path = path
-        v.ins.extend(ring_list(in_ring))
-        if terminal:
-            v.outs.append(g.results_ring())
-            return None
-        # per-edge capacity: a tuned Stage sizes its own outbound ring
-        ring = g.channel(getattr(skel, "capacity", None))
-        v.outs.append(ring)
-        return ring
-
-    raise TypeError(f"cannot lower {skel!r} to the process graph")
-
-
 class ProcProgram:
     """Procs lowering: the skeleton wired onto spawned processes over
     shared-memory SPSC rings — ``lower(skel, "procs")``.
@@ -1580,14 +814,7 @@ class ProcProgram:
                  pool: Optional[bool] = None,
                  trace: Any = False, metrics: Any = False,
                  monitor: Any = None):
-        if fuse and isinstance(skeleton, Pipeline):
-            force = fuse is True
-            thr = fuse_threshold_us
-            if not force and thr is None and _has_grained_stage(skeleton):
-                from .sched import calibrate_handoff_us
-                thr = calibrate_handoff_us()
-            skeleton = _fuse_pass(skeleton, threshold_us=thr, force=force)
-        self.skeleton = skeleton
+        self.skeleton = _fuse_for_lowering(skeleton, fuse, fuse_threshold_us)
         self.capacity = capacity
         self.slot_size = slot_size
         self.timeout = timeout
@@ -1605,7 +832,7 @@ class ProcProgram:
                       zero_copy=self.zero_copy, batch=self.batch,
                       pool=self.pool)
         # per-farm live counter boards exist only when a monitor will read
-        # them — a monitorless lowering allocates nothing extra
+        # them
         g.live_telemetry = self.monitor is not None
         try:
             # Build the driving Source separately (at path "in") so the
@@ -1677,13 +904,14 @@ class ProcAccelerator:
     For a plain farm — no emitter/collector node, no feedback edge, a
     ``pick()``-based scheduling policy (rr / ondemand / costmodel) — the
     accelerator runs **caller-side arbitration**: the calling thread IS
-    the dispatch and merge arbiter (tagging, placement, dedup-free
-    collection, reorder-by-tag), so the network is exactly ``nworkers``
-    processes and zero polling arbiters.  That is the paper's
-    self-offloading design taken literally, and on a small machine it
-    matters: every extra polling process is a core-thief.  Skeletons that
-    need an arbiter process (compositions, feedback loops, worksteal's
-    pump) fall back to the full process graph transparently.
+    the dispatch and merge arbiter (tagging and placement here, collection
+    and reorder-by-tag through a :class:`~repro.core.vertex.MergeVertex`
+    it drives itself), so the network is exactly ``nworkers`` processes
+    and zero polling arbiters.  That is the paper's self-offloading design
+    taken literally, and on a small machine it matters: every extra
+    polling process is a core-thief.  Skeletons that need an arbiter
+    process (compositions, feedback loops, worksteal's pump) fall back to
+    the full process graph transparently.
 
     ``offload`` opportunistically drains results while the target ring is
     full — the caller is part of the network, so it must not create a
@@ -1727,83 +955,46 @@ class ProcAccelerator:
         self._sched = make_scheduler(skel.scheduling)
         self._stats = FarmStats()
         self._in_rings: List[ShmRing] = []
-        self._out_rings: List[ShmRing] = []
         self._service_rings: List[ShmRing] = []
+        self._merge = merge = MergeVertex(TagSpace(), ordered=skel.ordered)
+        merge.rt, merge.stats = g, self._stats
         cap = skel.capacity or g.capacity
+        needs_service = self._sched.needs_service_stats
         for i, node in enumerate(skel.worker_nodes):
-            service = (g.channel(64)
-                       if self._sched.needs_service_stats else None)
-            if service is not None:
-                self._service_rings.append(service)
-            w = g.add(ProcWorkerVertex(node, i, service_ring=service,
-                                       name=f"ff-worker-{i}"))
+            w = g.add(WorkerVertex(node, i, record_service=needs_service,
+                                   name=f"ff-worker-{i}"))
+            w.stats = g.farm_stats(skel, w)
+            if needs_service:
+                w.service_ring = g.service_ring()
+                self._service_rings.append(w.service_ring)
             w.cpus = self._sched.worker_cpus(i, len(skel.worker_nodes))
             q_in, q_out = g.channel(cap), g.channel(cap)
             w.ins.append(q_in)
             w.outs.append(q_out)
             self._in_rings.append(q_in)
-            self._out_rings.append(q_out)
+            merge.ins.append(q_out)
         self._sched.bind(self._in_rings, self._stats)
         self._next_tag = 0
-        self._reorder: Dict[int, Any] = {}
-        self._next_out = 0
-        self._worker_eos = 0
         self._drain_backoff = _Backoff()
 
     # -- caller-side merge ---------------------------------------------------
-    def _collect(self, payload: Any) -> None:
-        if payload is GO_ON:
-            return  # the merge arbiter would have retired it silently
-        if isinstance(payload, _FarmEmitMany):
-            self._g.results.extend(payload)
-            return
-        self._g.results.append(payload)
-
     def _drain(self) -> bool:
-        """One pass over the worker output (and service) rings; returns
-        True if anything moved.  This IS MergeVertex._loop, inlined into
-        the caller."""
-        moved = False
+        """One pass over the worker service and output rings; True if
+        anything moved."""
         for ring in self._service_rings:
             while True:
                 upd = ring.pop()
                 if upd is _EMPTY:
                     break
                 self._sched.observe_service(upd[0], upd[1])
-        st = self._stats
-        for i, q in enumerate(self._out_rings):
-            for _ in range(_BATCH):
-                tok = q.pop()
-                if tok is _EMPTY:
-                    break
-                moved = True
-                if tok is EOS:
-                    self._worker_eos += 1
-                    break
-                if isinstance(tok, _WorkerStats):
-                    if tok.ewma is not None:
-                        st.service_ewma[tok.index] = tok.ewma
-                    continue
-                tag, issued, payload = tok
-                st.tasks_collected += 1
-                st.per_worker[i] = st.per_worker.get(i, 0) + 1
-                if issued:
-                    st.latencies.append(time.monotonic() - issued)
-                if self._farm.ordered:
-                    self._reorder[tag] = payload
-                    while self._next_out in self._reorder:
-                        self._collect(self._reorder.pop(self._next_out))
-                        self._next_out += 1
-                else:
-                    self._collect(payload)
-        return moved
+        return self._merge._collect()
 
     def _caller_done(self) -> bool:
         self._drain()
-        return self._worker_eos >= len(self._out_rings)
+        return len(self._merge._eos) >= len(self._merge.ins)
 
     def _network_dead(self) -> bool:
-        """A vertex raised (failure Event) or silently died (liveness
+        """A vertex raised (failure flag) or silently died (liveness
         probe): the caller's push loops must stop blocking on rings no
         process will ever drain."""
         if self._g.failed_event.is_set():
@@ -1878,8 +1069,7 @@ class ProcAccelerator:
         try:
             return self._g._wait_until(self._caller_done, timeout)
         finally:
-            # flush reorder residue + surface the merged FarmStats onto
-            # the IR node, as the graph path's stats ring would have
-            for t in sorted(self._reorder):
-                self._collect(self._reorder.pop(t))
+            # flush reorder residue + surface the caller's FarmStats onto
+            # the IR node (the workers' arrive over their control rings)
+            self._merge._flush()
             _fold_stats(self._farm.stats, self._stats)

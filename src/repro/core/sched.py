@@ -4,7 +4,7 @@ The paper's headline numbers on fine-grain streams (Sec. 6: 35-226% over
 OpenMP/Cilk/TBB on Smith-Waterman) come from two knobs working together:
 cheap lock-free hand-offs *and* smart task placement.  The hand-offs live
 in ``spsc.py``; this module is the placement knob, extracted out of the
-dispatch arbiter (``graph.DispatchVertex``) into a policy hierarchy so
+dispatch arbiter (``vertex.DispatchVertex``) into a policy hierarchy so
 ``lower(skel, "threads", ...)`` / ``Farm(scheduling=...)`` can pick — or a
 user can subclass — without touching the runtime:
 
@@ -126,7 +126,7 @@ class Scheduler:
                     nworkers: int) -> Optional[Tuple[int, ...]]:
         """Placement hint: CPUs worker ``index`` should be pinned to, or
         ``None`` for no pin.  Consumed at build time by the procs backend
-        (``ProcVertex.cpus``); the threads backend ignores it (one
+        (``Vertex.cpus``); the threads backend ignores it (one
         process, the OS balances threads)."""
         if not self.pin_cpus:
             return None
@@ -361,11 +361,8 @@ class KeyAffinity(Scheduler):
         return self._hash(key) % len(self.outs)
 
     def place(self, tok: Any, emit: Callable[[int, Any], None]) -> None:
-        # tok is graph.Token (threads), a (tag, issued, payload) tuple
-        # (procs wire format), or a raw payload (caller-side arbitration)
-        payload = tok.payload if hasattr(tok, "payload") else (
-            tok[2] if isinstance(tok, tuple) and len(tok) == 3 else tok)
-        emit(self.route(payload), tok)
+        # tok is the farm token (tag, issued, payload) on both runners
+        emit(self.route(tok[2]), tok)
 
 
 class BudgetBackpressure(RoundRobin):

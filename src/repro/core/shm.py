@@ -489,12 +489,12 @@ class ShmCounters:
     """``n`` single-writer u64 counters, one per cache line, in shared
     memory — the cross-process analogue of ``TagSpace``'s split counters.
 
-    ``procgraph`` uses a 2-counter board per wrap-around farm: slot 0
-    (``entered``) is written only by the dispatch arbiter, slot 1
-    (``retired``) only by the merge arbiter; each side reads the other's
-    slot benignly stale, with the same store-ordering argument as the
-    ring (the merge arbiter pushes looped-back tasks *before* bumping
-    ``retired``, so the dispatcher's quiescence check stays race-free).
+    ``procgraph`` uses a 1-counter board per wrap-around farm: the slot
+    (``retired``) is written only by the merge arbiter and read, benignly
+    stale, by the dispatch arbiter, with the same store-ordering argument
+    as the ring (the merge arbiter pushes looped-back tasks *before*
+    bumping ``retired``, so the dispatcher's quiescence check stays
+    race-free).
     """
 
     def __init__(self, n: int = 2, *, name: Optional[str] = None,
@@ -526,6 +526,10 @@ class ShmCounters:
         which runs after the writers have joined anyway)."""
         step = _CACHE_LINE // 8
         return tuple(self._idx[i * step] for i in range(self.n))
+
+    def set(self, i: int, value: int) -> None:
+        """Single-writer store (exactly one process may write slot i)."""
+        self._idx[i * (_CACHE_LINE // 8)] = value
 
     def add(self, i: int, delta: int = 1) -> None:
         """Single-writer increment (exactly one process may write slot i)."""

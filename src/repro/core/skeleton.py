@@ -11,9 +11,10 @@ with ``compose``/``>>`` (the paper's ∘), carrying ``ordered=``,
 Execution is a separate step, :func:`lower`:
 
 ``lower(skel, backend="threads")``
-    produces a :class:`ThreadProgram` over today's thread/SPSC-ring graph
-    runtime — PR 1's ``Net._build`` machinery, now driven by the IR (see
-    :func:`repro.core.graph.build`).  Ordered-stream semantics come from the
+    produces a :class:`~repro.core.graph.ThreadProgram` over the
+    thread/SPSC-ring graph runtime, wired by the shared walker
+    :func:`repro.core.vertex.build` (``"procs"`` is the same walker over
+    spawned processes).  Ordered-stream semantics come from the
     tagged-token collector.
 
 ``lower(skel, backend="mesh")``
@@ -44,8 +45,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple, Type
 
-from .obs import (MetricsRegistry, Tracer, farm_stats_snapshot,
-                  qualname as _obs_qualname)
+from .obs import MetricsRegistry, Tracer, qualname as _obs_qualname
 
 __all__ = [
     "GO_ON", "EmitMany", "KeyBatch", "ff_node", "FnNode", "FusedNode",
@@ -53,7 +53,7 @@ __all__ = [
     "Skeleton", "Stage", "Source", "Pipeline", "Farm", "Feedback",
     "AllToAll",
     "compose", "as_skeleton", "fuse", "walk_stats",
-    "LoweringError", "lower", "BACKENDS", "ThreadProgram", "MeshProgram",
+    "LoweringError", "lower", "BACKENDS", "MeshProgram",
 ]
 
 STAGE_AXIS = "skel_stage"
@@ -872,7 +872,7 @@ def _a2a_can_absorb(a2a: "Skeleton", stage: "Stage") -> bool:
     right nodes, so an absorbed stage would silently vanish there), and no
     batch-aware or budget-carrying right nodes (the ``FusedNode`` wrapper
     would hide ``accepts_batches``/``budget`` from the vertex and the
-    budget-board plumbing — see :func:`repro.core.a2a._a2a_budgets`)."""
+    budget-board plumbing — see :func:`repro.core.vertex._a2a_budgets`)."""
     return (isinstance(a2a, AllToAll) and not a2a.ordered
             and a2a.reduce is None and _stateless(stage.node)
             and not any(getattr(n, "accepts_batches", False)
@@ -978,7 +978,20 @@ def _has_grained_stage(skel: "Skeleton") -> bool:
     return isinstance(skel, Stage) and skel.grain is not None
 
 
-_fuse_pass = fuse  # ThreadProgram's `fuse=` parameter shadows the name
+def _fuse_for_lowering(skel: Skeleton, mode: Any,
+                       threshold_us: Optional[float]) -> Skeleton:
+    """The host lowerings' ``fuse=`` option: ``"auto"`` collapses
+    hand-offs whose declared stage ``grain=`` is below ``threshold_us``
+    (or, when None, the measured per-item hand-off cost — calibrated only
+    if some stage declares a grain); ``True`` force-fuses every eligible
+    adjacent pair; a false value leaves ``skel`` as it is."""
+    if not mode or not isinstance(skel, Pipeline):
+        return skel
+    force = mode is True
+    if not force and threshold_us is None and _has_grained_stage(skel):
+        from .sched import calibrate_handoff_us
+        threshold_us = calibrate_handoff_us()
+    return fuse(skel, threshold_us=threshold_us, force=force)
 
 
 # ---------------------------------------------------------------------------
@@ -1064,113 +1077,6 @@ def _coerce_monitor(monitor: Any):
     return Monitor()
 
 
-class ThreadProgram:
-    """Threads lowering: the skeleton wired onto the PR-1 graph runtime
-    (one thread per vertex, lock-free SPSC rings for every edge).
-
-    ``fuse`` controls the grain-aware fusion pass: ``"auto"`` (default)
-    collapses hand-offs whose declared stage ``grain=`` is below the
-    calibrated threshold (``fuse_threshold_us``, or the measured per-item
-    hand-off cost when None — calibration only runs if some stage declares
-    a grain); ``True`` force-fuses every eligible adjacent pair; ``False``
-    disables the pass.
-
-    ``trace=True`` (or a :class:`~repro.core.obs.Tracer`) gives every
-    vertex a sampled event lane; the merged
-    :class:`~repro.core.obs.Trace` lands on ``last_trace`` after each
-    call.  ``metrics=True`` (or a
-    :class:`~repro.core.obs.MetricsRegistry`) samples queue depths while
-    the run drains and absorbs the skeleton's ``FarmStats`` into a
-    :class:`~repro.core.obs.RunReport` on ``last_report``.
-
-    ``monitor=True`` (or a :class:`~repro.core.monitor.Monitor`) attaches
-    the continuous live sampler for the duration of each call: queue
-    depths, farm EWMAs and counters land in ``monitor.timeline`` while
-    the stream runs — see :mod:`repro.core.monitor`."""
-
-    backend = "threads"
-
-    def __init__(self, skeleton: Skeleton, *,
-                 queue_class: Optional[Type] = None, capacity: int = 512,
-                 fuse: Any = "auto", fuse_threshold_us: Optional[float] = None,
-                 trace: Any = False, metrics: Any = False,
-                 monitor: Any = None):
-        if fuse and isinstance(skeleton, Pipeline):
-            force = fuse is True
-            thr = fuse_threshold_us
-            if not force and thr is None and _has_grained_stage(skeleton):
-                from .sched import calibrate_handoff_us
-                thr = calibrate_handoff_us()
-            skeleton = _fuse_pass(skeleton, threshold_us=thr, force=force)
-        self.skeleton = skeleton
-        self.queue_class = queue_class
-        self.capacity = capacity
-        self.tracer = _coerce_tracer(trace)
-        self.metrics = _coerce_metrics(metrics)
-        self.monitor = _coerce_monitor(monitor)
-        self.last_trace = None
-        self.last_report = None
-
-    def to_graph(self, stream: Optional[Iterable[Any]] = None):
-        from . import graph  # the threads backend (PR-1 vertex machinery)
-        from .spsc import SPSCQueue
-        g = graph.Graph(queue_class=self.queue_class or SPSCQueue,
-                        capacity=self.capacity)
-        # a live monitor wants per-worker service EWMAs: opt the farm
-        # workers into the timing they otherwise skip (same flag the
-        # procs backend uses to arm its live counter boards)
-        g.live_telemetry = self.monitor is not None
-        # Build the driving Source separately (at path "in") so the user
-        # skeleton keeps its root IR paths — telemetry keys vertices by
-        # path, and wrapping in a fresh Pipeline would shift every
-        # top-level index by one.
-        in_ring = None
-        if stream is not None:
-            in_ring = graph.build(Source(stream), g, None, False, "in")
-        graph.build(self.skeleton, g, in_ring, True)
-        if self.tracer is not None:
-            g.tracer = self.tracer
-        return g
-
-    def __call__(self, items: Iterable[Any]) -> List[Any]:
-        xs = list(items)
-        g = self.to_graph(xs)
-        reg = self.metrics
-        mon = self.monitor
-        if mon is not None:
-            mon.attach(g, skeleton=self.skeleton, backend="threads")
-        try:
-            if reg is None:
-                out = g.run_and_wait()
-            else:
-                hw: Dict[str, int] = {}
-                t0 = time.monotonic()
-                # a short run can finish before the first poll below: the
-                # drain sampler runs inside wait() after the vertex threads
-                # join but before teardown, so every edge key still lands
-                # exactly once — and never races the caller's results drain
-                g.drain_samplers.append(lambda: g.sample_high_water(hw))
-                g.run()
-                while any(t.is_alive() for t in g._threads):
-                    g.sample_high_water(hw)
-                    time.sleep(0.0005)
-                out = g.wait()
-                farms = {q: farm_stats_snapshot(st)
-                         for q, st in walk_stats(self.skeleton)}
-                self.last_report = reg.finalize(reg.report(
-                    farms=farms, queues=hw,
-                    meta={"backend": "threads", "vertices": len(g.vertices),
-                          "items_in": len(xs), "items_out": len(out),
-                          "wall_s": time.monotonic() - t0}))
-        finally:
-            if mon is not None:
-                mon.detach()
-        if self.tracer is not None:
-            self.last_trace = self.tracer.trace()
-        return out
-
-
-BACKENDS["threads"] = ThreadProgram
 
 
 # ---------------------------------------------------------------------------
@@ -1458,23 +1364,3 @@ class MeshProgram:
         if self.metrics is not None:
             self.metrics.counter("mesh.compiles").inc()
         return fn
-
-
-def _contains_a2a(skel: Skeleton) -> bool:
-    if isinstance(skel, Pipeline):
-        return any(_contains_a2a(s) for s in skel.stages)
-    return isinstance(skel, AllToAll)
-
-
-def _mesh_backend(skeleton: Skeleton, **opts: Any):
-    """Mesh-backend factory: skeletons containing an :class:`AllToAll`
-    compile to the keyed-shuffle program (:class:`repro.core.a2a.
-    A2AMeshProgram` — dispatch-by-key exchange + segment reduction in one
-    ``shard_map``); everything else to :class:`MeshProgram`."""
-    if _contains_a2a(skeleton):
-        from .a2a import A2AMeshProgram
-        return A2AMeshProgram(skeleton, **opts)
-    return MeshProgram(skeleton, **opts)
-
-
-BACKENDS["mesh"] = _mesh_backend

@@ -54,6 +54,16 @@ def sleepy(x):
     return x
 
 
+def fb_slow_step(x):
+    time.sleep(0.0005)  # slower than the dispatch: its worker ring fills
+    return fb_step(x)
+
+
+def slow_f(x):
+    time.sleep(0.0005)
+    return f(x)
+
+
 def big_payload(x):
     return ("#" * 5000, x)  # forces the shm ring's spill side-channel
 

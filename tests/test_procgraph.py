@@ -8,7 +8,8 @@ and hygiene (no leaked /dev/shm segments).  All nodes live in
 ``tests/_procs_nodes.py`` — spawned children re-import the defining
 module, which must stay free of test-only deps.
 """
-import glob
+import os
+from multiprocessing import shared_memory
 
 import pytest
 
@@ -17,16 +18,26 @@ from repro.core import (EOS, Farm, Feedback, LoweringError, Pipeline,
                         ProcAccelerator, ProcProgram, Source, Stage, lower)
 
 
-def _segments():
-    return set(glob.glob("/dev/shm/psm_*"))
-
-
 @pytest.fixture(autouse=True)
-def no_leaked_segments():
-    """Every test must unlink every segment it caused to exist."""
-    before = _segments()
+def no_leaked_segments(monkeypatch):
+    """Every test must unlink every segment it creates.  The check follows
+    the segments this process creates (every ring, board and flag is made
+    caller-side), not the host's whole /dev/shm, which other test
+    processes share."""
+    made = []
+    real_init = shared_memory.SharedMemory.__init__
+
+    def recording_init(self, name=None, create=False, size=0, **kw):
+        real_init(self, name=name, create=create, size=size, **kw)
+        if create:
+            made.append(self.name)
+
+    monkeypatch.setattr(shared_memory.SharedMemory, "__init__",
+                        recording_init)
     yield
-    assert _segments() - before == set(), "leaked SharedMemory segments"
+    leaked = [n for n in made
+              if os.path.exists(os.path.join("/dev/shm", n.lstrip("/")))]
+    assert not leaked, f"leaked SharedMemory segments: {leaked}"
 
 
 def test_lower_returns_proc_program():
