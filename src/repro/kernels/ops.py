@@ -15,12 +15,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import obs
+from .decode_attention import (decode_attention as _decode, kv_block,
+                               kv_blocks_read)
 from .flash_attention import flash_attention as _flash
 from .smith_waterman import sw_pallas as _sw
 from .ssd_scan import ssd_scan as _ssd
 
 __all__ = ["smith_waterman", "smith_waterman_padded", "pad_subject",
-           "flash_attention_op", "ssd_scan_op", "build_profile", "BLOSUM50",
+           "flash_attention_op", "decode_attention_op", "kv_block",
+           "kv_blocks_read", "ssd_scan_op", "build_profile", "BLOSUM50",
            "AA_ALPHABET", "encode_seq", "resolve_interpret"]
 
 
@@ -124,6 +127,15 @@ def flash_attention_op(q, k, v, *, causal=True, window=None,
                        interpret: Optional[bool] = None, **kw):
     return _flash(q, k, v, causal=causal, window=window,
                   interpret=resolve_interpret(interpret), **kw)
+
+
+def decode_attention_op(q, k, v, at, positions, *,
+                        interpret: Optional[bool] = None):
+    """Decode attention of each slot over layer ``at`` of the K/V stacks
+    ``(*lead, B, T, kv·dh)``, reading the slot's blocks up to its
+    position (``decode_attention.kv_block`` says which caches it tiles)."""
+    return _decode(q, k, v, tuple(at), positions,
+                   interpret=resolve_interpret(interpret))
 
 
 def ssd_scan_op(x, dt, A, B, C, *, chunk=256, interpret: Optional[bool] = None):

@@ -58,6 +58,7 @@ from ..core.obs import MetricsRegistry, Tracer
 from ..core.sched import CostModel
 from ..core.skeleton import Farm, Source, compose, lower
 from ..core.spsc import SPSCQueue
+from ..kernels.ops import kv_block, kv_blocks_read
 from ..models import decode_step as model_decode, init_cache, init_params
 from ..models.config import ModelConfig
 from ..models.model import reset_cache_slot
@@ -121,7 +122,10 @@ class ServeEngine:
     (``serve.free_slot_steps``) as it dispatches a step, and the tokens
     it hands to requests (``serve.gen_slot_steps``) as it collects one;
     the row of a step dispatched after a request's end token is counted
-    by none."""
+    by none.  ``serve.kv_blocks_read`` adds, as each step is dispatched,
+    the K/V blocks that decode attention reads on a TPU in each layer for
+    the occupied slots (``kernels.ops.kv_blocks_read`` of each slot's
+    position); it stays 0 where the kernel cannot tile the cache."""
 
     def __init__(self, cfg: ModelConfig, *, max_batch: int = 4,
                  max_len: int = 256, seed: int = 0, params=None,
@@ -160,6 +164,12 @@ class ServeEngine:
         self._prompt_steps = self.metrics.counter("serve.prompt_slot_steps")
         self._gen_steps = self.metrics.counter("serve.gen_slot_steps")
         self._free_steps = self.metrics.counter("serve.free_slot_steps")
+        self._kv_blocks = self.metrics.counter("serve.kv_blocks_read")
+        # (T, positions a block) where the decode kernel tiles the cache
+        k = self.cache.get("k")
+        blk = None if k is None else kv_block(k.shape[-2], k.shape[-1],
+                                              k.dtype.itemsize)
+        self._kv_tiles = None if blk is None else (k.shape[-2], blk)
         self.last_report = None
         self.slo = slo
         self.tracer = None
@@ -258,6 +268,10 @@ class ServeEngine:
             self.steps_run += 1
             self._prompt_steps.inc(sum(not y for _, _, y in fed))
             self._free_steps.inc(self.max_batch - len(fed))
+            if self._kv_tiles:
+                T, blk = self._kv_tiles
+                self._kv_blocks.inc(sum(kv_blocks_read(int(pos[s]), T, blk)
+                                        for s, _, _ in fed))
             prev, self._inflight = self._inflight, (nxt, logits, fed)
             self._last = nxt
             if prev is not None:

@@ -15,7 +15,9 @@ enumerating the valid pairs statically (beyond-paper optimisation, §Perf).
 
 Decode (single new token against a KV cache) is a separate, simpler path;
 ``cached_decode_attention`` reads the decode cache, whose K/V keep heads
-and head dimension merged, (B, T, Hkv·Dh).
+and head dimension merged, (B, T, Hkv·Dh); it is the plain reference for
+the Pallas decode kernel that takes its place on a TPU
+(``repro/kernels/decode_attention.py``).
 All other shapes: q (B, S, H, Dh); k/v (B, T, Hkv, Dh) with H % Hkv == 0.
 """
 from __future__ import annotations

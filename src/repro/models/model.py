@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..kernels.ops import decode_attention_op, kv_block
 from ..parallel.context import (current_ctx, fsdp_gather, manual_model,
                                 psum_compat, shard)
 from .attention import attention, cached_decode_attention, decode_attention
@@ -329,6 +330,28 @@ def _write_kv(kv: LayerKV, k, v, pos, rolling: bool) -> LayerKV:
                        v=kv.v.at[at].set(v.reshape(B, -1).astype(kv.v.dtype)))
 
 
+def _cached_attention(q, kv: LayerKV, pos):
+    """Each row's decode self-attention over layer ``kv.at`` of the
+    stacks, at the indices at or below its position ``pos``.
+
+    Lowered for a TPU, the Pallas kernel reads the stacks where they lie
+    and only each row's blocks up to its position; every other platform
+    computes the plain ``cached_decode_attention`` over the layer.  So
+    does a TPU where the kernel cannot tile the cache (``kv_block``) or
+    under a mesh, where a Mosaic kernel is not partitioned."""
+    def plain(q, k, v, pos, *at):
+        return cached_decode_attention(q, k[at], v[at], pos)
+
+    def kernel(q, k, v, pos, *at):
+        return decode_attention_op(q, k, v, at, pos, interpret=False)
+
+    T, C = kv.k.shape[-2:]
+    if current_ctx() is not None or kv_block(T, C, kv.k.dtype.itemsize) is None:
+        return plain(q, kv.k, kv.v, pos, *kv.at)
+    return lax.platform_dependent(q, kv.k, kv.v, pos, *kv.at, tpu=kernel,
+                                  default=plain)
+
+
 def _attn_core(p, x, cfg: ModelConfig, *, positions, mode: str,
                kv_cache=None, rolling=False, ext_kv=None):
     """Shared attention path. Returns (delta, new cache or None): in
@@ -354,8 +377,7 @@ def _attn_core(p, x, cfg: ModelConfig, *, positions, mode: str,
     if mode == "decode" and ext_kv is None:
         pos = positions[:, 0]
         new_cache = _write_kv(kv_cache, k, v, pos, rolling)
-        attn = cached_decode_attention(q, new_cache.k[new_cache.at],
-                                       new_cache.v[new_cache.at], pos)
+        attn = _cached_attention(q, new_cache, pos)
     elif mode == "decode":  # cross-attn decode: attend the cached vision kv
         attn = decode_attention(q, k, v, jnp.full((B,), k.shape[1] - 1))
         new_cache = None

@@ -125,6 +125,84 @@ def test_flash_attention_matches_model_chunked_path():
 
 
 # --------------------------------------------------------------------------
+# Decode attention over the serving cache
+# --------------------------------------------------------------------------
+# (name, lead axes, at, T, heads, KV heads, head dim, positions, dtype):
+# f32 caches of T 1024 and kv·dh 128 or 384 take blocks of 512 positions
+_DECODE_CASES = [
+    ("edges_0_blk-1_blk_T-1", (2,), (1,), 1024, 4, 4, 32, [0, 511, 512, 1023],
+     jnp.float32),
+    ("ragged", (3,), (2,), 1024, 4, 4, 32, [3, 700, 64, 1000], jnp.float32),
+    ("rolling_full", (2,), (0,), 512, 4, 4, 32, [512, 700, 2000, 511],
+     jnp.float32),
+    ("gqa_head96", (2,), (1,), 1024, 8, 4, 96, [0, 95, 600, 1023],
+     jnp.float32),
+    ("vlm_two_level_at", (2, 3), (1, 2), 1024, 4, 4, 32, [10, 513, 0, 900],
+     jnp.float32),
+    ("bf16_head96", (2,), (1,), 512, 4, 4, 96, [5, 127, 128, 300],
+     jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize("case", _DECODE_CASES, ids=[c[0] for c in _DECODE_CASES])
+def test_decode_attention_kernel_matches_the_reference(case):
+    """The kernel, given the whole stacks and ``at``, equals
+    ``cached_decode_attention`` and ``decode_attention`` over the layer.
+    Above each slot's position the stacks hold NaN keys and huge values,
+    and past its last block NaN values too, as a recycled slot holds
+    stale entries: none of it may reach the output."""
+    from repro.models.attention import cached_decode_attention, decode_attention
+    _, lead, at, T, H, hkv, dh, positions, dtype = case
+    B, C = len(positions), hkv * dh
+    blk = ops.kv_block(T, C, jnp.dtype(dtype).itemsize)
+    assert blk is not None and T % blk == 0
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(T + C), 3)
+    q = jax.random.normal(k1, (B, 1, H, dh), dtype)
+    k = jax.random.normal(k2, (*lead, B, T, C), dtype)
+    v = jax.random.normal(k3, (*lead, B, T, C), dtype)
+    pos = jnp.asarray(positions, jnp.int32)
+    t = jnp.arange(T)[None, :, None]
+    above = t > pos[:, None, None]                       # (B, T, 1)
+    unread = t >= blk * (jnp.minimum(pos, T - 1) // blk + 1)[:, None, None]
+    kp = jnp.where(above, jnp.nan, k)
+    vp = jnp.where(unread, jnp.nan, jnp.where(above, 1e30, v))
+    got = np.asarray(ops.decode_attention_op(q, kp, vp, at, pos), np.float32)
+    want = cached_decode_attention(q, k[at], v[at], pos)
+    split = decode_attention(q, k[at].reshape(B, T, hkv, dh),
+                             v[at].reshape(B, T, hkv, dh), pos)
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    assert got.shape == (B, 1, H, dh)
+    np.testing.assert_allclose(got, np.asarray(want, np.float32), atol=tol, rtol=tol)
+    np.testing.assert_allclose(got, np.asarray(split, np.float32), atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("T,C,itemsize,blk", [
+    (2048, 3072, 2, 256),     # Phi-3-mini: 512 would take 12 MiB of VMEM
+    (2048, 1024, 2, 512),
+    (1024, 128, 4, 512),
+    (384, 3072, 2, 128),      # 256 does not divide 384
+    (2048, 96, 2, None),      # kv·dh not whole lanes
+    (64, 128, 4, None),       # shorter than a block
+])
+def test_kv_block_rule(T, C, itemsize, blk):
+    """One rule for the kernel's tiling and the engine's
+    ``serve.kv_blocks_read``: blocks a slot reads are those up to its
+    position, every block once a rolling cache is full.  The kernel
+    refuses a cache the rule does not tile."""
+    assert ops.kv_block(T, C, itemsize) == blk
+    if blk is None:
+        dtype = {2: jnp.bfloat16, 4: jnp.float32}[itemsize]
+        kv = jnp.zeros((1, 1, T, C), dtype)
+        with pytest.raises(ValueError):
+            ops.decode_attention_op(jnp.zeros((1, 1, 1, C), dtype), kv, kv,
+                                    (0,), jnp.zeros((1,), jnp.int32))
+    else:
+        assert [ops.kv_blocks_read(p, T, blk)
+                for p in (0, blk - 1, blk, T - 1, T, 5 * T)] == \
+            [1, 1, 2, T // blk, T // blk, T // blk]
+
+
+# --------------------------------------------------------------------------
 # SSD scan
 # --------------------------------------------------------------------------
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

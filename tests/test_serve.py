@@ -197,6 +197,24 @@ def test_slot_steps_are_counted_by_kind():
     assert all(not r.logits for r in eng.results)      # none asked for
 
 
+def test_kv_blocks_read_counts_each_slots_blocks_up_to_its_position():
+    """``serve.kv_blocks_read`` adds, each step, the blocks up to each
+    occupied slot's position.  Eight float32 KV heads of 16 make kv·dh
+    128; 384 positions take blocks of 128.  A request of P prompt tokens
+    and N new ones holds its slot at positions 0 .. P + N - 2, however it
+    is scheduled: 2 + 200 reads one block at 0..127 and two at 128..200
+    (128 + 2 * 73), 5 + 20 one at each of 24 steps, 1 + 3 one at each of
+    3: 301 in all."""
+    cfg = dense(n_heads=8, n_kv_heads=8)
+    assert cfg.n_kv_heads * cfg.hdim == 128
+    waves = [[([1, 2], 200), ([3] * 5, 20), ([4], 3)]]
+    eng = serve(cfg, waves, max_batch=2, max_len=384, keep=False)
+    assert eng.metrics.counter("serve.kv_blocks_read").value == 301
+    # a cache of kv·dh 64 is not tiled: the plain path, nothing counted
+    eng = serve(dense(), waves, max_batch=2, max_len=384, keep=False)
+    assert eng.metrics.counter("serve.kv_blocks_read").value == 0
+
+
 @pytest.mark.parametrize("prompt,max_new", [([], 4), ([1, 2], 0),
                                             ([1] * 20, 13)])
 def test_a_request_that_cannot_fit_a_slot_is_refused(prompt, max_new):

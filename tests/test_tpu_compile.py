@@ -190,3 +190,20 @@ def test_phi3_mini_serving_step_writes_the_cache_in_place(phi3_serve_step):
     assert layer_bytes == 100_663_296
     assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
     assert _kv_moves(compiled.as_text(), {k.size, k.size // k.shape[0]}) == []
+
+
+def test_phi3_mini_serving_step_reads_the_cache_through_the_decode_kernel(
+        phi3_serve_step):
+    """Lowered for a TPU, self-attention over the cache is the Pallas
+    decode kernel, one call in the layer scan's body, handed the whole K
+    and V stacks (never a layer sliced out), with no conditional left
+    for the plain path the other platforms take."""
+    compiled, cache = phi3_serve_step
+    hlo = compiled.as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    assert re.search(r"%decode_attention[.\d]* = ", calls[0])
+    stack = "bf16[" + ",".join(map(str, cache["k"].shape)) + "]"
+    assert calls[0].count(stack) == 2                 # K and V, whole
+    assert " conditional(" not in hlo
